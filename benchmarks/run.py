@@ -36,6 +36,7 @@ from benchmarks import (bench_chain, bench_crossover, bench_dims,
                         bench_gdt, bench_operators, bench_pipeline,
                         bench_roofline, bench_serve, bench_table3)
 from benchmarks.common import emit
+from repro.core.compile_cache import enable_compile_cache
 
 MODULES = {
     "chain": bench_chain,
@@ -60,6 +61,7 @@ def main() -> None:
                     help="write BENCH_<module>.json files (name -> "
                          "us_per_call) into OUTDIR")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = args.only.split(",") if args.only else list(MODULES)
     unknown = [n for n in names if n not in MODULES]

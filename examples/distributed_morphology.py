@@ -13,8 +13,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as D
 from repro.core import morphology as M
+from repro.core.compile_cache import enable_compile_cache
 from repro.data.images import blobs
 
+enable_compile_cache()
 n = len(jax.devices())
 rows = max(1, n // 2)
 cols = n // rows
@@ -28,15 +30,13 @@ marker = jnp.maximum(f, m)
 put = lambda x: jax.device_put(x, NamedSharding(mesh, P("r", "c")))  # noqa: E731
 
 # 64-step chain: halo exchanged once per 16 fused steps (4 exchanges)
-chain = D.distributed_chain(mesh, "r", "c", n=64, op="erode",
-                            backend="xla", fuse_k=16)
+chain = D.distributed_chain(mesh, "r", "c", n=64, op="erode", fuse_k=16)
 out = chain(put(f))
 ref = M.erode(f, 64)
 print("chain sharded == single-device:",
       bool(jnp.array_equal(out, ref)))
 
-rec = D.distributed_reconstruct(mesh, "r", "c", op="erode",
-                                backend="xla", fuse_k=16)
+rec = D.distributed_reconstruct(mesh, "r", "c", op="erode", fuse_k=16)
 out = rec(put(marker), put(m))
 ref = M.erode_reconstruct(marker, m)
 print("reconstruct sharded == single-device:",

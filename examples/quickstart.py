@@ -11,8 +11,11 @@ import jax.numpy as jnp
 
 from repro.api import E, asf_expr, compile, dome_expr, hmax_expr
 from repro.core import operators as OPS
+from repro.core.compile_cache import enable_compile_cache
 from repro.data.images import blobs
 from repro.kernels import ops
+
+enable_compile_cache()
 
 # a "Male"-like test image: smooth background + multi-scale blobs
 img = blobs(256, 256, np.uint8)

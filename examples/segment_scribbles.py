@@ -21,6 +21,7 @@ import argparse
 
 import numpy as np
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.data.images import blobs
 from repro.serve import Service
 
@@ -54,6 +55,7 @@ def scribble_rounds(img: np.ndarray, rounds: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=64)
     ap.add_argument("--rounds", type=int, default=3)

@@ -23,6 +23,7 @@ import json
 
 import numpy as np
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.data.images import basins, blobs, border_objects
 from repro.serve import Service
 
@@ -54,6 +55,7 @@ def make_frames(n, size, mixed_sizes):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=12)
     ap.add_argument("--size", type=int, default=256)

@@ -12,8 +12,8 @@ Severities:
 ``WARN``
     a domain-conditional hazard (e.g. int32 QDT residuals can overflow
     only for images spanning more than the int32 range) or a
-    readiness diagnostic (e.g. halo blocks narrower than the 128-lane
-    Mosaic tiling — ROADMAP item 3).  Reported, never fatal.
+    readiness diagnostic (e.g. a column tile off the 128-lane Mosaic
+    tiling).  Reported, never fatal.
 """
 from __future__ import annotations
 

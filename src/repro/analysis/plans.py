@@ -18,14 +18,18 @@ plan could do):
   column-tiled, ``fuse_k ≤ requeue_halo · tile_w`` — otherwise a
   wavefront outruns the re-activated neighbourhood and convergence is
   detected too early;
-* compaction capacity within the activity grid.
+* compaction capacity within the activity grid;
+* a scoped-VMEM limit (``vmem_limit_bytes``) one v5e core can grant.
 
-Mosaic-readiness (WARN, ROADMAP item 3): interpret-mode Pallas accepts
-any block geometry, but on-TPU Mosaic wants last-dim tiles in 128-lane
-multiples and sublane counts per dtype.  The diagnostics flag every
-block the 2-D tile kernels would feed Mosaic that violates that —
-``fuse_k``-wide corner/side halos, non-lane-multiple ``tile_w``/
-``width_pad``, patch widths ``tile_w + 2·fuse_k``.
+Mosaic-readiness (WARN): interpret-mode Pallas accepts any block
+geometry, but on-TPU Mosaic wants a block's last dim to be a 128-lane
+multiple (or the whole array) and its rows a multiple of the dtype's
+sublane count.  ``tests/test_mosaic_compile.py`` compiles every kernel
+for v5e at the planner's own plans; these diagnostics flag a plan
+outside what that test covers — a ``width_pad`` or ``tile_w`` off the
+lane grid (the tile grid's centre and halo blocks are ``tile_w`` wide
+then) or a ``fuse_k``/``band_h`` off the sublane grid.  Compact patches
+(``tile_w + 2·fuse_k`` wide) are whole-array-wide blocks and compile.
 """
 from __future__ import annotations
 
@@ -72,6 +76,12 @@ def check_plan(plan, shape3=None) -> list:
         err(f"schedule={plan.schedule!r} is not a known schedule "
             "('wavefront' | 'raster') — the executable would fall "
             "through to the wavefront path silently")
+
+    # v5e core VMEM by value, as for the schedule names above
+    if not 0 < getattr(plan, "vmem_limit_bytes", 1) <= 128 * 1024 * 1024:
+        err(f"vmem_limit_bytes={plan.vmem_limit_bytes} outside (0, 128 "
+            "MiB]: Mosaic cannot grant the kernels more VMEM than one "
+            "v5e core has")
 
     if plan.tile_w < 0:
         err(f"tile_w={plan.tile_w} < 0")
@@ -128,8 +138,8 @@ def check_plan(plan, shape3=None) -> list:
 
 
 def check_mosaic_readiness(plan, dtype=None) -> list:
-    """WARN-level diagnostics for on-TPU (interpret=False) lowering —
-    the known PR 4 blocker tracked as ROADMAP item 3."""
+    """WARN-level diagnostics for on-TPU (interpret=False) lowering of
+    a plan the v5e compile test does not cover."""
     out = []
 
     def warn(subject, msg):
@@ -138,22 +148,11 @@ def check_mosaic_readiness(plan, dtype=None) -> list:
     if plan.width_pad % LANES:
         warn("mosaic/width",
              f"width_pad={plan.width_pad} is not a {LANES}-lane multiple")
-    if plan.tile_w:
-        if plan.tile_w % LANES:
-            warn("mosaic/tile",
-                 f"tile_w={plan.tile_w} is not a {LANES}-lane multiple "
-                 "(centre blocks of the 2-D tile kernels)")
-        if plan.fuse_k % LANES:
-            warn("mosaic/halo",
-                 f"corner/side halo blocks are fuse_k={plan.fuse_k} "
-                 f"lanes wide — narrower than the {LANES}-lane tiling "
-                 "Mosaic wants (tile_specs NOTE; widen or re-fetch for "
-                 "interpret=False)")
-        if (plan.tile_w + 2 * plan.fuse_k) % LANES:
-            warn("mosaic/patch",
-                 f"compact patch width tile_w+2K="
-                 f"{plan.tile_w + 2 * plan.fuse_k} is not a {LANES}-lane "
-                 "multiple (gathered workspace of the compact kernels)")
+    if plan.tile_w and plan.tile_w % LANES:
+        warn("mosaic/tile",
+             f"tile_w={plan.tile_w} is not a {LANES}-lane multiple "
+             "(centre and corner/side halo blocks of the 2-D tile "
+             "kernels are tile_w lanes wide)")
     if dtype is not None:
         sub = SUBLANES.get(np.dtype(dtype).itemsize, 8)
         if plan.fuse_k % sub:

@@ -731,14 +731,16 @@ class Executable:
             raise AssertionError(seg.kind)
 
     def _chain2(self, x2, op, n, plan):
-        from repro.kernels.ops import _INTERPRET, _stacked, _unstacked
+        from repro.kernels.ops import _interpret, _stacked, _unstacked
 
         full, rem = divmod(n, plan.fuse_k)
         if full:
             def chunk(x, _):
                 return chain_step(
                     x, op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
-                    interpret=_INTERPRET, bands_per_image=plan.n_bands,
+                    interpret=_interpret(),
+                    vmem_limit_bytes=plan.vmem_limit_bytes,
+                    bands_per_image=plan.n_bands,
                 ), None
             x2, _ = jax.lax.scan(chunk, x2, None, length=full)
         if rem:
@@ -753,14 +755,16 @@ class Executable:
         return x2
 
     def _geodesic2(self, f2, m2, op, n, plan):
-        from repro.kernels.ops import _INTERPRET, _stacked, _unstacked
+        from repro.kernels.ops import _interpret, _stacked, _unstacked
 
         full, rem = divmod(n, plan.fuse_k)
         if full:
             def chunk(x, _):
                 y, _ = geodesic_chain_step(
                     x, m2, op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
-                    interpret=_INTERPRET, bands_per_image=plan.n_bands,
+                    interpret=_interpret(),
+                    vmem_limit_bytes=plan.vmem_limit_bytes,
+                    bands_per_image=plan.n_bands,
                 )
                 return y, None
             f2, _ = jax.lax.scan(chunk, f2, None, length=full)

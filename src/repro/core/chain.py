@@ -53,9 +53,19 @@ import math
 
 import jax.numpy as jnp
 
-#: VMEM budget we allow a kernel working set to claim (bytes).  TPU v5e has
-#: 16 MiB/core more or less; leave half for double buffering + compiler slop.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+#: VMEM on one TPU v5e TensorCore (bytes).
+VMEM_BYTES = 128 * 1024 * 1024
+#: Scoped VMEM Mosaic grants one kernel on v5e unless the kernel asks
+#: for more; a plan never asks for less (``ChainPlan.vmem_limit_bytes``).
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+#: VMEM the planner sizes a band's working set to (bytes, counted by
+#: :func:`working_set_bytes`): a quarter of the core's VMEM.
+DEFAULT_VMEM_BUDGET = 32 * 1024 * 1024
+#: Values a kernel keeps live per streamed plane, in the work dtype, on
+#: top of its double-buffered blocks.  Fit to the scoped VMEM Mosaic
+#: allocates for the ten kernels at the 1024² and 2048 px plans (v5e):
+#: gdt's weight arithmetic is the high end, at about 4 per plane.
+LIVE_PER_PLANE = 5
 
 #: TPU lane count — last-dim tiles should be multiples of this.
 LANES = 128
@@ -75,6 +85,44 @@ CONVERGENT_TARGET_TILES = 16
 #: image with directional forward/backward passes (FastGeodis-style —
 #: wins when the wavefront is dense and activity tracking is overhead).
 SCHEDULES = ("wavefront", "raster")
+
+
+def work_dtype(dtype):
+    """The dtype the kernels compute in: 8- and 16-bit integers widen to
+    int32 (Mosaic has no narrow-integer min/max, compare or select on
+    v5e); every other dtype is kept.  Widening is exact for min/max,
+    the mask clamps and the change test, so results stay bit-exact."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.integer) and dtype.itemsize < 4:
+        return jnp.dtype(jnp.int32)
+    return dtype
+
+
+def side_width(tile_w: int) -> int:
+    """Lane width of the left/right halo blocks of the 2-D tile grid:
+    one 128-lane group when ``tile_w`` is lane-aligned (Mosaic's block
+    tiling), else the whole neighbouring tile.  The kernel keeps only
+    the ``fuse_k`` lanes next to the centre."""
+    return LANES if tile_w % LANES == 0 else tile_w
+
+
+def working_set_bytes(band_h: int, fuse_k: int, width_pad: int,
+                      tile_w: int, dtype, planes: int) -> int:
+    """VMEM one grid step of the plan's kernels holds (bytes) — the one
+    model the planner sizes bands by and sets ``vmem_limit_bytes`` from.
+
+    Each of ``planes`` same-shaped operands streams in as a halo-stacked
+    ``(band_h + 2·fuse_k)``-row block and out as a ``band_h``-row block,
+    both double-buffered by the grid pipeline, and keeps
+    ``LIVE_PER_PLANE`` stack-sized values live in the kernel.
+    Everything is counted in the work dtype, across the widest block
+    the plan drives: the full row, or a tile with its two side halos."""
+    cols = width_pad
+    if tile_w:
+        cols = max(cols, tile_w + 2 * side_width(tile_w))
+    stack = (band_h + 2 * fuse_k) * cols * work_dtype(dtype).itemsize
+    band = band_h * cols * work_dtype(dtype).itemsize
+    return planes * (2 * (stack + band) + LIVE_PER_PLANE * stack)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +145,7 @@ class ChainPlan:
     compact_threshold: float = 0.0   # active fraction below which to compact
     tile_w: int = 0      # column-tile width; 0 = full-width row bands
     schedule: str = "wavefront"  # "wavefront" (requeue) | "raster" (sweeps)
+    vmem_limit_bytes: int = SCOPED_VMEM_BYTES  # Mosaic's scoped VMEM cap
 
     def __post_init__(self):
         # The one place the band/fuse/tile contract is validated (the
@@ -136,6 +185,11 @@ class ChainPlan:
             raise ValueError(
                 f"schedule={self.schedule!r} must be one of {SCHEDULES}"
             )
+        if not 0 < self.vmem_limit_bytes <= VMEM_BYTES:
+            raise ValueError(
+                f"vmem_limit_bytes={self.vmem_limit_bytes} must be in "
+                f"(0, {VMEM_BYTES}]"
+            )
 
     @property
     def key(self) -> tuple:
@@ -148,7 +202,7 @@ class ChainPlan:
         return (self.band_h, self.fuse_k, self.width_pad, self.height_pad,
                 self.n_bands, self.n_chunks, self.n_images,
                 self.requeue_halo, self.compact_threshold, self.tile_w,
-                self.schedule)
+                self.schedule, self.vmem_limit_bytes)
 
     @property
     def total_bands(self) -> int:
@@ -201,9 +255,15 @@ def plan_chain(
 ) -> ChainPlan:
     """Choose (TH, K) so the working set fits VMEM.
 
-    ``n_images_resident`` counts extra same-shaped operands the kernel
-    holds (e.g. the geodesic mask, QDT's r/d planes).  ``n_images`` is
-    the batch size of the vertical image stack the plan will drive.
+    ``n_images_resident`` counts the same-shaped planes the kernels
+    stream (1 for a plain chain, 2 with the geodesic mask, 3 for QDT's
+    and gdt's planes).  ``n_images`` is the batch size of the vertical
+    image stack the plan will drive.  The band is the tallest whose
+    :func:`working_set_bytes` fits ``vmem_budget``, and the plan's
+    ``vmem_limit_bytes`` is that working set (never below the scoped
+    default).  The working set is counted in the kernels' work dtype
+    (8- and 16-bit integers compute in int32), while ``fuse_k`` rounds
+    to the storage dtype's sublane tiling.
 
     ``convergent=True`` marks a convergence-driven chain (reconstruction
     / QDT): the planner caps the band height near
@@ -231,18 +291,6 @@ def plan_chain(
     # round K to a sublane multiple so halo blocks tile cleanly
     fuse_k = max(sub, math.ceil(fuse_k / sub) * sub)
 
-    if band_h is None:
-        # working set ≈ (1 + n_resident)·(TH + 2K)·W·b  + TH·W·b scratch
-        per_row = (2 + n_images_resident) * w_pad * b
-        band_h = max(fuse_k, (vmem_budget - 2 * fuse_k * per_row) // per_row)
-        band_h = max(fuse_k, (band_h // fuse_k) * fuse_k)  # TH % K == 0
-        band_h = min(band_h, 512)
-        if convergent:
-            # requeue granularity: aim for ~CONVERGENT_TARGET_BANDS bands
-            target = math.ceil(height / CONVERGENT_TARGET_BANDS)
-            target = max(fuse_k, math.ceil(target / fuse_k) * fuse_k)
-            band_h = min(band_h, target)
-
     if compact_threshold is None:
         compact_threshold = 0.5 if convergent else 0.0
 
@@ -259,6 +307,20 @@ def plan_chain(
             if tile_w >= w_pad or w_pad % tile_w:
                 tile_w = 0
 
+    def working_set(th):
+        return working_set_bytes(th, fuse_k, w_pad, tile_w, dtype,
+                                 n_images_resident)
+
+    if band_h is None:
+        band_h = max(fuse_k, 512 // fuse_k * fuse_k)  # TH % K == 0
+        if convergent:
+            # requeue granularity: aim for ~CONVERGENT_TARGET_BANDS bands
+            target = math.ceil(height / CONVERGENT_TARGET_BANDS)
+            band_h = min(band_h, max(fuse_k, math.ceil(target / fuse_k)
+                                     * fuse_k))
+        while band_h > fuse_k and working_set(band_h) > vmem_budget:
+            band_h -= fuse_k
+
     h_pad = math.ceil(height / band_h) * band_h
     n_bands = h_pad // band_h
     n_chunks = math.ceil((chain_len or fuse_k) / fuse_k)
@@ -269,6 +331,8 @@ def plan_chain(
         compact_threshold=compact_threshold,
         tile_w=tile_w,
         schedule=schedule,
+        vmem_limit_bytes=min(VMEM_BYTES, max(SCOPED_VMEM_BYTES,
+                                             working_set(band_h))),
     )
 
 

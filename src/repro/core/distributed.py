@@ -20,6 +20,10 @@ column neighbour (two-phase halo exchange).
 Convergence of distributed reconstruction is a ``psum`` of the per-device
 changed flags — the collective version of the paper's ``converged`` flag
 (Alg. 4).
+
+The shard maps run with ``check_vma=False``: the per-shard Pallas
+kernels declare their outputs without mesh-axis variance, which the
+check would otherwise reject.
 """
 from __future__ import annotations
 
@@ -28,17 +32,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    shard_map = jax.shard_map
-    SHMAP_KW = {}
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-    # the experimental shard_map has no replication rule for while_loop;
-    # disable the (purely diagnostic) replication check.  SHMAP_KW is
-    # the single home for this shim — splat it into every shard_map call.
-    SHMAP_KW = {"check_rep": False}
 
 from repro.core import morphology as M
 from repro.core.chain import plan_chain
@@ -53,8 +46,7 @@ from repro.kernels.common import ident_for
 def _exchange_axis(local, k: int, axis_name, fill, axis: int):
     """Attach a k-deep halo along ``axis`` from mesh neighbours on
     ``axis_name`` (global edges are filled with the absorbing value)."""
-    # psum of 1 == axis size; jax.lax.axis_size only exists in newer jax
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         pad = [(0, 0)] * local.ndim
         pad[axis] = (k, k)
@@ -101,13 +93,14 @@ def distributed_chain(
     *,
     n: int,
     op: str = "erode",
-    backend: str = "xla",
+    backend: str | None = None,
     fuse_k: int | None = None,
 ):
     """Build a jitted sharded n-step elementary chain over ``mesh``.
 
     Returns a function image -> image; the image is sharded
-    P(row_axes, col_axes) on entry and exit.
+    P(row_axes, col_axes) on entry and exit.  ``backend`` runs each
+    shard's fused chunk (None = the platform policy default).
     """
     spec = P(row_axes, col_axes)
     row_axes_t = row_axes if isinstance(row_axes, tuple) else (row_axes,)
@@ -139,8 +132,8 @@ def distributed_chain(
             f_loc = _crop(ext, rem, bool(col_axes_t))
         return f_loc
 
-    sharded = shard_map(local_fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                        **SHMAP_KW)
+    sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,),
+                            out_specs=spec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -155,13 +148,14 @@ def distributed_reconstruct(
     col_axes: str | Sequence[str] | None = None,
     *,
     op: str = "erode",
-    backend: str = "xla",
+    backend: str | None = None,
     fuse_k: int | None = None,
     max_chunks: int | None = None,
 ):
     """Build a jitted sharded ε_rec/δ_rec over ``mesh``.
 
     Returns (marker, mask) -> reconstructed, both sharded P(rows, cols).
+    ``backend`` as for :func:`distributed_chain`.
     """
     spec = P(row_axes, col_axes)
     row_axes_t = row_axes if isinstance(row_axes, tuple) else (row_axes,)
@@ -185,9 +179,9 @@ def distributed_reconstruct(
         if limit is None:
             # pixel-count bound, like kernels.ops.reconstruct: geodesic
             # paths under a serpentine mask can exceed the H+W diameter
-            h = f_loc.shape[0] * jax.lax.psum(1, row_axes_t[0])
+            h = f_loc.shape[0] * jax.lax.axis_size(row_axes_t)
             w = f_loc.shape[1] * (
-                jax.lax.psum(1, col_axes_t[0]) if col_axes_t else 1
+                jax.lax.axis_size(col_axes_t) if col_axes_t else 1
             )
             limit = (h * w) // k + 2
 
@@ -209,7 +203,8 @@ def distributed_reconstruct(
         )
         return out
 
-    sharded = shard_map(
-        local_fn, mesh=mesh, in_specs=(spec, spec), out_specs=spec, **SHMAP_KW
+    sharded = jax.shard_map(
+        local_fn, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
+        check_vma=False,
     )
     return jax.jit(sharded)

@@ -9,8 +9,37 @@ documented in ``docs/ARCHITECTURE.md``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.chain import side_width, work_dtype
+
+
+def widen(x):
+    """``x`` in its :func:`work_dtype` (a no-op for 32-bit dtypes)."""
+    return x.astype(work_dtype(x.dtype))
+
+
+def smem_spec():
+    """A whole-array block in SMEM: the per-cell scalar flags
+    (``active``/``valid``/``base`` in, ``changed`` out) are indexed by
+    grid position inside the kernel instead of riding (1, 1) VMEM
+    blocks, which Mosaic refuses."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def changed_flag(new, old):
+    """1 iff any element of ``new`` differs from ``old`` (int32 scalar)."""
+    return jnp.max((new != old).astype(jnp.int32))
+
+
+def fused_steps(step, x, fuse_k: int):
+    """``step`` applied ``fuse_k`` times.  A loop, not an unrolled
+    Python ``for``: the kernel's code (and its compile time) then does
+    not grow with K."""
+    return jax.lax.fori_loop(0, fuse_k, lambda _, y: step(y), x)
 
 
 def image_edges(i, bands_per_image: int):
@@ -63,17 +92,15 @@ def tile_specs(band_h: int, tile_w: int, fuse_k: int, h: int, w: int):
     """The nine BlockSpecs feeding one (band_h, tile_w) cell of a 2-D
     grid its centre block and eight clamped neighbour halos, in
     ``assemble_tile`` order (tl, top, tr, left, mid, right, bl, bot,
-    br).  Clamped edge reads are pinned in-kernel.
-
-    NOTE (on-TPU follow-up): the corner/side halo blocks are only
-    ``fuse_k`` lanes wide — fine in interpret mode, but narrower than
-    the 128-lane tiling Mosaic wants; interpret=False validation may
-    need them widened or fetched differently.
+    br).  Clamped edge reads are pinned in-kernel.  The corner and side
+    halos are ``side_width(tile_w)`` lanes wide, so every block is
+    lane-aligned; ``assemble_tile`` slices their ``fuse_k`` lanes.
     """
+    sw = side_width(tile_w)
     r = band_h // fuse_k   # fuse_k-row blocks per band
-    c = tile_w // fuse_k   # fuse_k-col blocks per tile
+    c = tile_w // sw       # side-width blocks per tile
     last_r = h // fuse_k - 1
-    last_c = w // fuse_k - 1
+    last_c = w // sw - 1
 
     def up(i):
         return jnp.maximum(i * r - 1, 0)
@@ -87,7 +114,7 @@ def tile_specs(band_h: int, tile_w: int, fuse_k: int, h: int, w: int):
     def rt(j):
         return jnp.minimum((j + 1) * c, last_c)
 
-    kk, kw, bk = (fuse_k, fuse_k), (fuse_k, tile_w), (band_h, fuse_k)
+    kk, kw, bk = (fuse_k, sw), (fuse_k, tile_w), (band_h, sw)
     return [
         pl.BlockSpec(kk, lambda i, j: (up(i), lf(j))),
         pl.BlockSpec(kw, lambda i, j: (up(i), j)),
@@ -102,35 +129,46 @@ def tile_specs(band_h: int, tile_w: int, fuse_k: int, h: int, w: int):
 
 
 def assemble_tile(parts, edges, ident):
-    """Assemble one (band_h + 2K, tile_w + 2K) working stack from the
-    nine blocks of a 2-D tiled grid step, pinning out-of-image halos.
+    """Assemble one (band_h + 2K, tile_w + 2K) working stack, in the
+    work dtype, from the nine blocks of a 2-D tiled grid step, pinning
+    out-of-image halos.
 
     ``parts`` are the (tl, top, tr, left, mid, right, bl, bot, br)
     kernel refs; ``edges`` the (at_top, at_bot, at_left, at_right)
-    scalars for this grid step.  Edge halos read *clamped* blocks (the
-    BlockSpec index maps clip at the array border), so every block whose
-    true source lies outside the image is replaced with ``ident`` here —
-    corners pin when either of their two axes is at an edge.  The result
-    is the 2-D analogue of the row kernels' top/mid/bot concatenation:
-    after K elementary steps the centre (band_h, tile_w) window is
-    exact.
+    scalars for this grid step; ``ident`` the pin value in the work
+    dtype.  The left-hand blocks contribute their last K lanes and the
+    right-hand ones their first K (see ``tile_specs``).  Edge halos
+    read *clamped* blocks (the BlockSpec index maps clip at the array
+    border), so every block whose true source lies outside the image is
+    replaced with ``ident`` here — corners pin when either of their two
+    axes is at an edge.  The result is the 2-D analogue of the row
+    kernels' top/mid/bot concatenation: after K elementary steps the
+    centre (band_h, tile_w) window is exact.
     """
     tl, top, tr, lf, mid, rt, bl, bot, br = parts
     at_top, at_bot, at_lf, at_rt = edges
+    k = top.shape[0]
+
+    def left(ref):
+        return widen(ref[...][:, ref.shape[1] - k:])
+
+    def right(ref):
+        return widen(ref[...][:, :k])
+
     row_t = jnp.concatenate([
-        jnp.where(jnp.logical_or(at_top, at_lf), ident, tl[...]),
-        jnp.where(at_top, ident, top[...]),
-        jnp.where(jnp.logical_or(at_top, at_rt), ident, tr[...]),
+        jnp.where(jnp.logical_or(at_top, at_lf), ident, left(tl)),
+        jnp.where(at_top, ident, widen(top[...])),
+        jnp.where(jnp.logical_or(at_top, at_rt), ident, right(tr)),
     ], axis=1)
     row_m = jnp.concatenate([
-        jnp.where(at_lf, ident, lf[...]),
-        mid[...],
-        jnp.where(at_rt, ident, rt[...]),
+        jnp.where(at_lf, ident, left(lf)),
+        widen(mid[...]),
+        jnp.where(at_rt, ident, right(rt)),
     ], axis=1)
     row_b = jnp.concatenate([
-        jnp.where(jnp.logical_or(at_bot, at_lf), ident, bl[...]),
-        jnp.where(at_bot, ident, bot[...]),
-        jnp.where(jnp.logical_or(at_bot, at_rt), ident, br[...]),
+        jnp.where(jnp.logical_or(at_bot, at_lf), ident, left(bl)),
+        jnp.where(at_bot, ident, widen(bot[...])),
+        jnp.where(jnp.logical_or(at_bot, at_rt), ident, right(br)),
     ], axis=1)
     return jnp.concatenate([row_t, row_m, row_b], axis=0)
 

@@ -37,24 +37,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (elementary_3x3, ident_for, image_edges,
-                                  row_specs)
+from repro.kernels.common import (elementary_3x3, fused_steps, ident_for,
+                                  image_edges, row_specs, widen)
 
 
 def _chain_kernel(x_top, x_mid, x_bot, out, *, op: str, fuse_k: int,
                   band_h: int, bands_per_image: int):
-    ident = ident_for(op, x_mid.dtype)
+    ident = widen(ident_for(op, x_mid.dtype))
 
     at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
-    top = jnp.where(at_top, ident, x_top[...])
-    bot = jnp.where(at_bot, ident, x_bot[...])
-    stack = jnp.concatenate([top, x_mid[...], bot], axis=0)
+    top = jnp.where(at_top, ident, widen(x_top[...]))
+    bot = jnp.where(at_bot, ident, widen(x_bot[...]))
+    stack = jnp.concatenate([top, widen(x_mid[...]), bot], axis=0)
 
-    for _ in range(fuse_k):
-        stack = elementary_3x3(stack, op)
+    stack = fused_steps(lambda x: elementary_3x3(x, op), stack, fuse_k)
 
-    out[...] = stack[fuse_k : fuse_k + band_h, :]
+    out[...] = stack[fuse_k : fuse_k + band_h, :].astype(out.dtype)
 
 
 def chain_step(
@@ -63,7 +63,8 @@ def chain_step(
     op: str,
     fuse_k: int,
     band_h: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     bands_per_image: int | None = None,
 ) -> jnp.ndarray:
     """Apply K fused elementary filters to a pre-padded image (stack).
@@ -71,7 +72,8 @@ def chain_step(
     ``x``: (H_pad, W_pad) with H_pad % band_h == 0, band_h % fuse_k == 0,
     padding filled with the lattice identity for ``op``.  For a vertical
     stack of N images pass ``bands_per_image`` so the halo is pinned at
-    each image's edges rather than only the stack's.
+    each image's edges rather than only the stack's.  ``interpret``
+    runs the kernel in the Pallas interpreter (off-TPU validation).
     """
     h, w = x.shape
     assert h % band_h == 0 and band_h % fuse_k == 0, (h, band_h, fuse_k)
@@ -83,11 +85,15 @@ def chain_step(
     kern = functools.partial(_chain_kernel, op=op, fuse_k=fuse_k,
                              band_h=band_h, bands_per_image=bands_per_image)
 
+    in_specs = row_specs(band_h, fuse_k, h, w)
+    out_spec = pl.BlockSpec((band_h, w), lambda i: (i, 0))
     return pl.pallas_call(
         kern,
         grid=(n_bands,),
-        in_specs=row_specs(band_h, fuse_k, h, w),
-        out_specs=pl.BlockSpec((band_h, w), lambda i: (i, 0)),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((h, w), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(x, x, x)

@@ -43,8 +43,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (assemble_tile, image_edges, row_specs,
+from repro.kernels.common import (assemble_tile, changed_flag, fused_steps,
+                                  image_edges, row_specs, smem_spec,
                                   tile_edges, tile_specs)
 
 #: Absorbing halo/pad identities per plane.
@@ -69,6 +71,7 @@ def _shift2(x, dy, dx, fill):
         x = jnp.concatenate(
             [x[:, -dx:], jnp.full((h, -dx), fill, x.dtype)], axis=1)
     return x
+
 
 
 #: The 8-connected neighbourhood.
@@ -107,8 +110,7 @@ def elementary_gdt(d, i, s, lamb: float):
 def _gdt_update(d, i, s, window, *, fuse_k: int, lamb: float):
     """The K-step relaxation loop shared by every gdt grid shape."""
     (lo, hi), (cl, cr) = window
-    for _ in range(fuse_k):
-        d = elementary_gdt(d, i, s, lamb)
+    d = fused_steps(lambda x: elementary_gdt(x, i, s, lamb), d, fuse_k)
     return d[lo:hi, cl:cr]
 
 
@@ -119,14 +121,15 @@ def _gdt_kernel(
 ):
     # program_id is not available inside pl.when branches in interpret
     # mode — read it at kernel top level.
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
+    band = pl.program_id(0)
+    at_top, at_bot = image_edges(band, bands_per_image)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[band] == 0)
     def _passthrough():
         d_out[...] = d_mid[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[band] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[band] > 0)
     def _compute():
         def stack3(top, mid, bot, ident):
             t = jnp.where(at_top, jnp.asarray(ident, mid.dtype), top[...])
@@ -142,9 +145,7 @@ def _gdt_kernel(
             fuse_k=fuse_k, lamb=lamb,
         )
         d_out[...] = centre
-        changed[...] = (
-            jnp.any(centre != d_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[band] = changed_flag(centre, d_mid[...])
 
 
 def gdt_chain_step(
@@ -155,7 +156,8 @@ def gdt_chain_step(
     lamb: float,
     fuse_k: int,
     band_h: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -163,7 +165,8 @@ def gdt_chain_step(
 
     ``d``/``i``/``s`` are same-shaped float planes (see the module
     docstring for their roles); ``active`` optionally skips converged
-    bands.  Returns (d', changed) — changed is (n_bands, 1) int32.
+    bands; ``interpret`` runs the kernel in the Pallas interpreter.
+    Returns (d', changed) — changed is (n_bands, 1) int32.
     """
     h, w = d.shape
     assert h % band_h == 0 and band_h % fuse_k == 0
@@ -176,26 +179,28 @@ def gdt_chain_step(
         active = jnp.ones((n_bands, 1), jnp.int32)
 
     top_spec, mid_spec, bot_spec = row_specs(band_h, fuse_k, h, w)
-    flag_spec = pl.BlockSpec((1, 1), lambda b: (b, 0))
 
     kern = functools.partial(
         _gdt_kernel, fuse_k=fuse_k, band_h=band_h, lamb=float(lamb),
         bands_per_image=bands_per_image,
     )
-    return pl.pallas_call(
+    d2, changed = pl.pallas_call(
         kern,
         grid=(n_bands,),
-        in_specs=[flag_spec,
+        in_specs=[smem_spec(),
                   top_spec, mid_spec, bot_spec,
                   top_spec, mid_spec, bot_spec,
                   top_spec, mid_spec, bot_spec],
-        out_specs=[mid_spec, flag_spec],
+        out_specs=[mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), d.dtype),
-            jax.ShapeDtypeStruct((n_bands, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_bands,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(active, d, d, d, i, i, i, s, s, s)
+    )(active.reshape(n_bands), d, d, d, i, i, i, s, s, s)
+    return d2, changed.reshape(n_bands, 1)
 
 
 def _gdt_tile_kernel(
@@ -208,16 +213,18 @@ def _gdt_tile_kernel(
     d_parts, i_parts, s_parts = refs[:9], refs[9:18], refs[18:27]
     d_out, changed = refs[27:]
     d_mid = d_parts[4]
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
-    at_lf, at_rt = tile_edges(pl.program_id(1), n_tiles)
+    bi, tj = pl.program_id(0), pl.program_id(1)
+    cell = bi * n_tiles + tj
+    at_top, at_bot = image_edges(bi, bands_per_image)
+    at_lf, at_rt = tile_edges(tj, n_tiles)
     edges = (at_top, at_bot, at_lf, at_rt)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[cell] == 0)
     def _passthrough():
         d_out[...] = d_mid[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[cell] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[cell] > 0)
     def _compute():
         d = assemble_tile(d_parts, edges, jnp.asarray(D_IDENT, d_mid.dtype))
         i = assemble_tile(i_parts, edges, jnp.asarray(I_IDENT, d_mid.dtype))
@@ -228,9 +235,7 @@ def _gdt_tile_kernel(
             fuse_k=fuse_k, lamb=lamb,
         )
         d_out[...] = centre
-        changed[...] = (
-            jnp.any(centre != d_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[cell] = changed_flag(centre, d_mid[...])
 
 
 def gdt_tile_step(
@@ -242,7 +247,8 @@ def gdt_tile_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -264,24 +270,26 @@ def gdt_tile_step(
     if active is None:
         active = jnp.ones((n_bands, n_tiles), jnp.int32)
 
-    flag_spec = pl.BlockSpec((1, 1), lambda b, t: (b, t))
     mid_spec = pl.BlockSpec((band_h, tile_w), lambda b, t: (b, t))
     plane = tile_specs(band_h, tile_w, fuse_k, h, w)
     kern = functools.partial(
         _gdt_tile_kernel, fuse_k=fuse_k, band_h=band_h, tile_w=tile_w,
         lamb=float(lamb), bands_per_image=bands_per_image, n_tiles=n_tiles,
     )
-    return pl.pallas_call(
+    d2, changed = pl.pallas_call(
         kern,
         grid=(n_bands, n_tiles),
-        in_specs=[flag_spec] + plane + plane + plane,
-        out_specs=[mid_spec, flag_spec],
+        in_specs=[smem_spec()] + plane + plane + plane,
+        out_specs=[mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), d.dtype),
-            jax.ShapeDtypeStruct((n_bands, n_tiles), jnp.int32),
+            jax.ShapeDtypeStruct((n_bands * n_tiles,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(active, *([d] * 9), *([i] * 9), *([s] * 9))
+    )(active.reshape(n_bands * n_tiles), *([d] * 9), *([i] * 9), *([s] * 9))
+    return d2, changed.reshape(n_bands, n_tiles)
 
 
 def _gdt_compact_kernel(
@@ -290,13 +298,14 @@ def _gdt_compact_kernel(
 ):
     lo, hi = fuse_k, fuse_k + band_h
     cl, cr = fuse_k, fuse_k + tile_w
+    c = pl.program_id(0)
 
-    @pl.when(valid[0, 0] == 0)
+    @pl.when(valid[c] == 0)
     def _passthrough():
         d_out[...] = d_patch[lo:hi, cl:cr]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[c] = 0
 
-    @pl.when(valid[0, 0] > 0)
+    @pl.when(valid[c] > 0)
     def _compute():
         centre0 = d_patch[lo:hi, cl:cr]
         centre = _gdt_update(
@@ -304,9 +313,7 @@ def _gdt_compact_kernel(
             ((lo, hi), (cl, cr)), fuse_k=fuse_k, lamb=lamb,
         )
         d_out[...] = centre
-        changed[...] = (
-            jnp.any(centre != centre0).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[c] = changed_flag(centre, centre0)
 
 
 def gdt_compact_step(
@@ -319,7 +326,8 @@ def gdt_compact_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ):
     """Compacted-grid gdt chunk on driver-gathered active cells.
 
@@ -337,20 +345,22 @@ def gdt_compact_step(
 
     patch_spec = pl.BlockSpec((ph, pw), lambda c: (c, 0))
     mid_spec = pl.BlockSpec((band_h, tile_w), lambda c: (c, 0))
-    flag_spec = pl.BlockSpec((1, 1), lambda c: (c, 0))
 
     kern = functools.partial(
         _gdt_compact_kernel, fuse_k=fuse_k, band_h=band_h, tile_w=tile_w,
         lamb=float(lamb),
     )
-    return pl.pallas_call(
+    d2, changed = pl.pallas_call(
         kern,
         grid=(cap,),
-        in_specs=[flag_spec, patch_spec, patch_spec, patch_spec],
-        out_specs=[mid_spec, flag_spec],
+        in_specs=[smem_spec(), patch_spec, patch_spec, patch_spec],
+        out_specs=[mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((cap * band_h, tile_w), d_patch.dtype),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
+            jax.ShapeDtypeStruct((cap,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(valid, d_patch, i_patch, s_patch)
+    )(valid.reshape(cap), d_patch, i_patch, s_patch)
+    return d2, changed.reshape(cap, 1)

@@ -50,10 +50,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (assemble_tile, elementary_3x3, ident_for,
-                                  image_edges, row_specs, tile_edges,
-                                  tile_specs)
+from repro.kernels.common import (assemble_tile, changed_flag,
+                                  elementary_3x3, fused_steps, ident_for,
+                                  image_edges, row_specs, smem_spec,
+                                  tile_edges, tile_specs, widen)
+
+
+
+def _geodesic_steps(stack, mask, op: str, fuse_k: int):
+    """K elementary geodesic steps: ε₁/δ₁, then the mask clamp."""
+    clamp = jnp.maximum if op == "erode" else jnp.minimum
+    return fused_steps(lambda x: clamp(elementary_3x3(x, op), mask), stack,
+                       fuse_k)
 
 
 def _geodesic_kernel(
@@ -63,37 +73,34 @@ def _geodesic_kernel(
     # program_id must be read outside the pl.when bodies (the branches
     # are compiled as plain cond branches in interpret mode, where the
     # primitive has no lowering).
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
+    i = pl.program_id(0)
+    at_top, at_bot = image_edges(i, bands_per_image)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[i] == 0)
     def _passthrough():
         # converged band: pass the input through, report no change.
         out[...] = f_mid[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[i] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[i] > 0)
     def _compute():
-        ident = ident_for(op, f_mid.dtype)
+        ident = widen(ident_for(op, f_mid.dtype))
         # Pin the out-of-image halo: marker ← identity, mask ←
         # identity, so the pad region is absorbing and transmits
         # nothing (also between stacked batch images).
-        ftop = jnp.where(at_top, ident, f_top[...])
-        fbot = jnp.where(at_bot, ident, f_bot[...])
-        mtop = jnp.where(at_top, ident, m_top[...])
-        mbot = jnp.where(at_bot, ident, m_bot[...])
+        ftop = jnp.where(at_top, ident, widen(f_top[...]))
+        fbot = jnp.where(at_bot, ident, widen(f_bot[...]))
+        mtop = jnp.where(at_top, ident, widen(m_top[...]))
+        mbot = jnp.where(at_bot, ident, widen(m_bot[...]))
 
-        stack = jnp.concatenate([ftop, f_mid[...], fbot], axis=0)
-        mask = jnp.concatenate([mtop, m_mid[...], mbot], axis=0)
-
-        clamp = jnp.maximum if op == "erode" else jnp.minimum
-        for _ in range(fuse_k):
-            stack = clamp(elementary_3x3(stack, op), mask)
+        f0 = widen(f_mid[...])
+        stack = jnp.concatenate([ftop, f0, fbot], axis=0)
+        mask = jnp.concatenate([mtop, widen(m_mid[...]), mbot], axis=0)
+        stack = _geodesic_steps(stack, mask, op, fuse_k)
 
         centre = stack[fuse_k : fuse_k + band_h, :]
-        out[...] = centre
-        changed[...] = (
-            jnp.any(centre != f_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        out[...] = centre.astype(out.dtype)
+        changed[i] = changed_flag(centre, f0)
 
 
 def geodesic_chain_step(
@@ -103,7 +110,8 @@ def geodesic_chain_step(
     op: str,
     fuse_k: int,
     band_h: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -112,7 +120,8 @@ def geodesic_chain_step(
     ``f``/``m`` are (H, W) with H a multiple of ``band_h`` — possibly a
     vertical stack of ``H // (bands_per_image · band_h)`` images.
     ``active`` is an optional (n_bands, 1) int32 activity vector; bands
-    with 0 are skipped (input copied through, flag 0).
+    with 0 are skipped (input copied through, flag 0).  ``interpret``
+    runs the kernel in the Pallas interpreter (off-TPU validation).
 
     Returns (new_marker, changed) with changed an (n_bands, 1) int32.
     """
@@ -126,8 +135,8 @@ def geodesic_chain_step(
     if active is None:
         active = jnp.ones((n_bands, 1), jnp.int32)
 
-    act_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
     plane = row_specs(band_h, fuse_k, h, w)
+    out_spec = pl.BlockSpec((band_h, w), lambda i: (i, 0))
 
     kern = functools.partial(
         _geodesic_kernel, op=op, fuse_k=fuse_k, band_h=band_h,
@@ -136,18 +145,17 @@ def geodesic_chain_step(
     out, changed = pl.pallas_call(
         kern,
         grid=(n_bands,),
-        in_specs=[act_spec] + plane + plane,
-        out_specs=[
-            pl.BlockSpec((band_h, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        in_specs=[smem_spec()] + plane + plane,
+        out_specs=[out_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), f.dtype),
-            jax.ShapeDtypeStruct((n_bands, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_bands,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(active, f, f, f, m, m, m)
-    return out, changed
+    )(active.reshape(n_bands), f, f, f, m, m, m)
+    return out, changed.reshape(n_bands, 1)
 
 
 def _geodesic_tile_kernel(
@@ -160,30 +168,27 @@ def _geodesic_tile_kernel(
     f_parts, m_parts = refs[:9], refs[9:18]
     out, changed = refs[18], refs[19]
     f_mid = f_parts[4]
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
-    at_lf, at_rt = tile_edges(pl.program_id(1), n_tiles)
+    bi, tj = pl.program_id(0), pl.program_id(1)
+    cell = bi * n_tiles + tj
+    at_top, at_bot = image_edges(bi, bands_per_image)
+    at_lf, at_rt = tile_edges(tj, n_tiles)
     edges = (at_top, at_bot, at_lf, at_rt)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[cell] == 0)
     def _passthrough():
         out[...] = f_mid[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[cell] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[cell] > 0)
     def _compute():
-        ident = ident_for(op, f_mid.dtype)
+        ident = widen(ident_for(op, f_mid.dtype))
         stack = assemble_tile(f_parts, edges, ident)
         mask = assemble_tile(m_parts, edges, ident)
-
-        clamp = jnp.maximum if op == "erode" else jnp.minimum
-        for _ in range(fuse_k):
-            stack = clamp(elementary_3x3(stack, op), mask)
+        stack = _geodesic_steps(stack, mask, op, fuse_k)
 
         centre = stack[fuse_k : fuse_k + band_h, fuse_k : fuse_k + tile_w]
-        out[...] = centre
-        changed[...] = (
-            jnp.any(centre != f_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        out[...] = centre.astype(out.dtype)
+        changed[cell] = changed_flag(centre, widen(f_mid[...]))
 
 
 def geodesic_tile_step(
@@ -194,7 +199,8 @@ def geodesic_tile_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -218,8 +224,8 @@ def geodesic_tile_step(
     if active is None:
         active = jnp.ones((n_bands, n_tiles), jnp.int32)
 
-    act_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
     plane = tile_specs(band_h, tile_w, fuse_k, h, w)
+    out_spec = pl.BlockSpec((band_h, tile_w), lambda i, j: (i, j))
     kern = functools.partial(
         _geodesic_tile_kernel, op=op, fuse_k=fuse_k, band_h=band_h,
         tile_w=tile_w, bands_per_image=bands_per_image, n_tiles=n_tiles,
@@ -227,16 +233,17 @@ def geodesic_tile_step(
     out, changed = pl.pallas_call(
         kern,
         grid=(n_bands, n_tiles),
-        in_specs=[act_spec] + plane + plane,
-        out_specs=[pl.BlockSpec((band_h, tile_w), lambda i, j: (i, j)),
-                   act_spec],
+        in_specs=[smem_spec()] + plane + plane,
+        out_specs=[out_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), f.dtype),
-            jax.ShapeDtypeStruct((n_bands, n_tiles), jnp.int32),
+            jax.ShapeDtypeStruct((n_bands * n_tiles,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(active, *([f] * 9), *([m] * 9))
-    return out, changed
+    )(active.reshape(n_bands * n_tiles), *([f] * 9), *([m] * 9))
+    return out, changed.reshape(n_bands, n_tiles)
 
 
 def _geodesic_compact_kernel(
@@ -245,25 +252,21 @@ def _geodesic_compact_kernel(
 ):
     lo, hi = fuse_k, fuse_k + band_h
     cl, cr = fuse_k, fuse_k + tile_w
+    c = pl.program_id(0)
 
-    @pl.when(valid[0, 0] == 0)
+    @pl.when(valid[c] == 0)
     def _passthrough():
         out[...] = f_patch[lo:hi, cl:cr]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[c] = 0
 
-    @pl.when(valid[0, 0] > 0)
+    @pl.when(valid[c] > 0)
     def _compute():
-        stack = f_patch[...]
-        mask = m_patch[...]
+        stack = widen(f_patch[...])
         centre0 = stack[lo:hi, cl:cr]
-        clamp = jnp.maximum if op == "erode" else jnp.minimum
-        for _ in range(fuse_k):
-            stack = clamp(elementary_3x3(stack, op), mask)
+        stack = _geodesic_steps(stack, widen(m_patch[...]), op, fuse_k)
         centre = stack[lo:hi, cl:cr]
-        out[...] = centre
-        changed[...] = (
-            jnp.any(centre != centre0).astype(jnp.int32).reshape(1, 1)
-        )
+        out[...] = centre.astype(out.dtype)
+        changed[c] = changed_flag(centre, centre0)
 
 
 def geodesic_compact_step(
@@ -275,7 +278,8 @@ def geodesic_compact_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ):
     """Compacted-grid variant: the driver has already gathered each
     active cell into a (band_h + 2K, tile_w + 2K) *patch* — centre plus
@@ -296,7 +300,6 @@ def geodesic_compact_step(
 
     patch_spec = pl.BlockSpec((ph, pw), lambda i: (i, 0))
     mid_spec = pl.BlockSpec((band_h, tile_w), lambda i: (i, 0))
-    flag_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
 
     kern = functools.partial(
         _geodesic_compact_kernel, op=op, fuse_k=fuse_k, band_h=band_h,
@@ -305,12 +308,14 @@ def geodesic_compact_step(
     out, changed = pl.pallas_call(
         kern,
         grid=(cap,),
-        in_specs=[flag_spec, patch_spec, patch_spec],
-        out_specs=[mid_spec, flag_spec],
+        in_specs=[smem_spec(), patch_spec, patch_spec],
+        out_specs=[mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((cap * band_h, tile_w), f_patch.dtype),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
+            jax.ShapeDtypeStruct((cap,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(valid, f_patch, m_patch)
-    return out, changed
+    )(valid.reshape(cap), f_patch, m_patch)
+    return out, changed.reshape(cap, 1)

@@ -20,8 +20,9 @@ through ``repro.api.compile``, which
   4. crops back once.
 
 ``backend``:
-  * ``"pallas"``  — the fused kernels (interpret=True on CPU; on TPU the
-    same code path compiles natively with interpret=False).
+  * ``"pallas"``  — the fused kernels (interpret=True off TPU, decided
+    when the program is traced; on TPU the same code path compiles
+    natively with interpret=False).
   * ``"xla"``     — the pure-jnp oracle bodies; what the framework runs
     when Pallas is unavailable.  Still one compiled program per chain
     (unlike the per-filter "naive" baseline).
@@ -88,7 +89,12 @@ from repro.kernels.geodesic_chain import (geodesic_chain_step,
 from repro.kernels.qdt_chain import (qdt_chain_step, qdt_compact_step,
                                      qdt_tile_step)
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter: everywhere but
+    a TPU.  Asked when a program is traced, never at import, so that
+    importing ``repro`` initialises no backend."""
+    return jax.default_backend() != "tpu"
 
 
 def _api():
@@ -325,7 +331,8 @@ def morph_chain(
 
     def chunk(x, _):
         return chain_step(x, op=op, fuse_k=k, band_h=plan.band_h,
-                          interpret=_INTERPRET,
+                          interpret=_interpret(),
+                          vmem_limit_bytes=plan.vmem_limit_bytes,
                           bands_per_image=plan.n_bands), None
 
     if full:
@@ -425,7 +432,9 @@ def geodesic_chain(
     def chunk(x, _):
         y, _ = geodesic_chain_step(
             x, mp, op=op, fuse_k=k, band_h=plan.band_h,
-            interpret=_INTERPRET, bands_per_image=plan.n_bands,
+            interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes,
+            bands_per_image=plan.n_bands,
         )
         return y, None
 
@@ -645,12 +654,15 @@ def _scheduled_reconstruct(fp, mp, plan: ChainPlan, op: str, max_chunks: int,
         if plan.n_tiles > 1:
             return geodesic_tile_step(
                 x, mp, op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
-                tile_w=plan.tile_w, interpret=_INTERPRET, active=active,
+                tile_w=plan.tile_w, interpret=_interpret(),
+                vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
                 bands_per_image=plan.n_bands,
             )
         return geodesic_chain_step(
             x, mp, op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
-            interpret=_INTERPRET, active=active, bands_per_image=plan.n_bands,
+            interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
+            bands_per_image=plan.n_bands,
         )
 
     def gather_const(idx):
@@ -661,7 +673,8 @@ def _scheduled_reconstruct(fp, mp, plan: ChainPlan, op: str, max_chunks: int,
         new_mid, ch = geodesic_compact_step(
             f_patch, mask_patch, valid,
             op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
-            tile_w=_cell_tile_w(plan), interpret=_INTERPRET,
+            tile_w=_cell_tile_w(plan), interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes,
         )
         x = _scatter_mid(x, idx, new_mid, plan)
         return x, _scatter_flags(ch, idx, plan)
@@ -807,13 +820,15 @@ def _scheduled_qdt(fp, plan: ChainPlan, max_chunks: int, rp=None, dp=None,
                 x, r, d, jnp.broadcast_to(base, (plan.total_bands,
                                                  plan.n_tiles)),
                 fuse_k=k, band_h=plan.band_h, tile_w=plan.tile_w,
-                interpret=_INTERPRET, active=active,
+                interpret=_interpret(),
+                vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
                 bands_per_image=plan.n_bands,
             )
         else:
             x, r, d, ch = qdt_chain_step(
                 x, r, d, base, fuse_k=k, band_h=plan.band_h,
-                interpret=_INTERPRET, active=active,
+                interpret=_interpret(),
+                vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
                 bands_per_image=plan.n_bands,
             )
         return (x, r, d), ch
@@ -830,7 +845,8 @@ def _scheduled_qdt(fp, plan: ChainPlan, max_chunks: int, rp=None, dp=None,
         f2, r2, d2, ch = qdt_compact_step(
             f_patch, rm, dm, valid, base_slots,
             fuse_k=k, band_h=plan.band_h, tile_w=_cell_tile_w(plan),
-            interpret=_INTERPRET,
+            interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes,
         )
         x = _scatter_mid(x, idx, f2, plan)
         r = _scatter_mid(r, idx, r2, plan)
@@ -913,12 +929,14 @@ def _scheduled_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_chunks: int,
         if plan.n_tiles > 1:
             return gdt_tile_step(
                 d, ip, sp, lamb=lamb, fuse_k=k, band_h=plan.band_h,
-                tile_w=plan.tile_w, interpret=_INTERPRET, active=active,
+                tile_w=plan.tile_w, interpret=_interpret(),
+                vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
                 bands_per_image=plan.n_bands,
             )
         return gdt_chain_step(
             d, ip, sp, lamb=lamb, fuse_k=k, band_h=plan.band_h,
-            interpret=_INTERPRET, active=active,
+            interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes, active=active,
             bands_per_image=plan.n_bands,
         )
 
@@ -932,7 +950,8 @@ def _scheduled_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_chunks: int,
         new_mid, ch = gdt_compact_step(
             d_patch, i_patch, s_patch, valid,
             lamb=lamb, fuse_k=k, band_h=plan.band_h,
-            tile_w=_cell_tile_w(plan), interpret=_INTERPRET,
+            tile_w=_cell_tile_w(plan), interpret=_interpret(),
+            vmem_limit_bytes=plan.vmem_limit_bytes,
         )
         d = _scatter_mid(d, idx, new_mid, plan)
         return d, _scatter_flags(ch, idx, plan)

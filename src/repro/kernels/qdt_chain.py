@@ -27,27 +27,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (assemble_tile, elementary_3x3, ident_for,
-                                  image_edges, qdt_acc_dtype, row_specs,
-                                  tile_edges, tile_specs)
+from repro.kernels.common import (assemble_tile, changed_flag,
+                                  elementary_3x3, ident_for, image_edges,
+                                  qdt_acc_dtype, row_specs, smem_spec,
+                                  tile_edges, tile_specs, widen)
+
 
 
 def _qdt_update(stack, r, d, j0, window, *, fuse_k: int, acc_dtype):
     """The K-step masked-store loop shared by every QDT grid shape.
 
     ``window`` slices the centre (band_h, tile_w) region out of the
-    halo-extended ``stack``; r/d are centre-only.  Returns the final
-    centre, r, d."""
+    halo-extended ``stack`` (already in the work dtype); r/d are
+    centre-only.  Returns the final centre, r, d."""
     (lo, hi), (cl, cr) = window
-    for k in range(fuse_k):
+
+    def step(k, carry):
+        stack, r, d = carry
         nxt = elementary_3x3(stack, "erode")
         res = (stack[lo:hi, cl:cr].astype(acc_dtype)
                - nxt[lo:hi, cl:cr].astype(acc_dtype))
         upd = res > r
-        r = jnp.where(upd, res, r)
-        d = jnp.where(upd, j0 + (k + 1), d)
-        stack = nxt
+        return nxt, jnp.where(upd, res, r), jnp.where(upd, j0 + k + 1, d)
+
+    stack, r, d = jax.lax.fori_loop(0, fuse_k, step, (stack, r, d))
     return stack[lo:hi, cl:cr], r, d
 
 
@@ -56,41 +61,42 @@ def _qdt_kernel(
     changed,
     *, fuse_k: int, band_h: int, acc_dtype, bands_per_image: int,
 ):
-    # ``base`` is blocked per band: each band reads the elementary-erosion
-    # count already applied to *its image*, so ragged-converged stacks
-    # keep per-image distance indices (a finished image's counter stops
-    # advancing with the rest of the batch).
+    # ``base`` holds one entry per band: each band reads the
+    # elementary-erosion count already applied to *its image*, so
+    # ragged-converged stacks keep per-image distance indices (a
+    # finished image's counter stops advancing with the rest of the
+    # batch).
     # program_id is not available inside pl.when branches in interpret
     # mode — read it at kernel top level.
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
+    i = pl.program_id(0)
+    at_top, at_bot = image_edges(i, bands_per_image)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[i] == 0)
     def _passthrough():
         # converged band: pass all planes through, report no change.
         f_out[...] = f_mid[...]
         r_out[...] = r_in[...]
         d_out[...] = d_in[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[i] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[i] > 0)
     def _compute():
-        ident = ident_for("erode", f_mid.dtype)
-        top = jnp.where(at_top, ident, f_top[...])
-        bot = jnp.where(at_bot, ident, f_bot[...])
-        stack = jnp.concatenate([top, f_mid[...], bot], axis=0)
+        ident = widen(ident_for("erode", f_mid.dtype))
+        top = jnp.where(at_top, ident, widen(f_top[...]))
+        bot = jnp.where(at_bot, ident, widen(f_bot[...]))
+        f0 = widen(f_mid[...])
+        stack = jnp.concatenate([top, f0, bot], axis=0)
 
         w = f_mid.shape[1]
         centre, r, d = _qdt_update(
-            stack, r_in[...], d_in[...], base[0, 0],
+            stack, r_in[...], d_in[...], base[i],
             ((fuse_k, fuse_k + band_h), (0, w)),
             fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre
+        f_out[...] = centre.astype(f_out.dtype)
         r_out[...] = r
         d_out[...] = d
-        changed[...] = (
-            jnp.any(centre != f_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[i] = changed_flag(centre, f0)
 
 
 def qdt_chain_step(
@@ -101,7 +107,8 @@ def qdt_chain_step(
     *,
     fuse_k: int,
     band_h: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -111,7 +118,8 @@ def qdt_chain_step(
     erosions already applied to each band's image — per *band* so the
     batched driver can give every stacked image its own distance offset
     (a (1, 1) array is broadcast for the unbatched callers).
-    ``active`` optionally skips converged bands (see module docstring).
+    ``active`` optionally skips converged bands (see module docstring);
+    ``interpret`` runs the kernel in the Pallas interpreter.
     Returns (f', r', d', changed) — changed is (n_bands, 1) int32.
     """
     h, w = f.shape
@@ -129,26 +137,28 @@ def qdt_chain_step(
     assert r.dtype == acc_dtype and d.dtype == jnp.int32
 
     top_spec, mid_spec, bot_spec = row_specs(band_h, fuse_k, h, w)
-    flag_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
 
     kern = functools.partial(
         _qdt_kernel, fuse_k=fuse_k, band_h=band_h, acc_dtype=acc_dtype,
         bands_per_image=bands_per_image,
     )
-    return pl.pallas_call(
+    f2, r2, d2, changed = pl.pallas_call(
         kern,
         grid=(n_bands,),
-        in_specs=[flag_spec, flag_spec, top_spec, mid_spec, bot_spec,
+        in_specs=[smem_spec(), smem_spec(), top_spec, mid_spec, bot_spec,
                   mid_spec, mid_spec],
-        out_specs=[mid_spec, mid_spec, mid_spec, flag_spec],
+        out_specs=[mid_spec, mid_spec, mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), f.dtype),
             jax.ShapeDtypeStruct((h, w), acc_dtype),
             jax.ShapeDtypeStruct((h, w), jnp.int32),
-            jax.ShapeDtypeStruct((n_bands, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_bands,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(base, active, f, f, f, r, d)
+    )(base.reshape(n_bands), active.reshape(n_bands), f, f, f, r, d)
+    return f2, r2, d2, changed.reshape(n_bands, 1)
 
 
 def _qdt_tile_kernel(
@@ -162,31 +172,31 @@ def _qdt_tile_kernel(
     r_in, d_in = refs[9], refs[10]
     f_out, r_out, d_out, changed = refs[11:]
     f_mid = f_parts[4]
-    at_top, at_bot = image_edges(pl.program_id(0), bands_per_image)
-    at_lf, at_rt = tile_edges(pl.program_id(1), n_tiles)
+    bi, tj = pl.program_id(0), pl.program_id(1)
+    cell = bi * n_tiles + tj
+    at_top, at_bot = image_edges(bi, bands_per_image)
+    at_lf, at_rt = tile_edges(tj, n_tiles)
 
-    @pl.when(active[0, 0] == 0)
+    @pl.when(active[cell] == 0)
     def _passthrough():
         f_out[...] = f_mid[...]
         r_out[...] = r_in[...]
         d_out[...] = d_in[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[cell] = 0
 
-    @pl.when(active[0, 0] > 0)
+    @pl.when(active[cell] > 0)
     def _compute():
-        ident = ident_for("erode", f_mid.dtype)
+        ident = widen(ident_for("erode", f_mid.dtype))
         stack = assemble_tile(f_parts, (at_top, at_bot, at_lf, at_rt), ident)
         centre, r, d = _qdt_update(
-            stack, r_in[...], d_in[...], base[0, 0],
+            stack, r_in[...], d_in[...], base[cell],
             ((fuse_k, fuse_k + band_h), (fuse_k, fuse_k + tile_w)),
             fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre
+        f_out[...] = centre.astype(f_out.dtype)
         r_out[...] = r
         d_out[...] = d
-        changed[...] = (
-            jnp.any(centre != f_mid[...]).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[cell] = changed_flag(centre, widen(f_mid[...]))
 
 
 def qdt_tile_step(
@@ -198,7 +208,8 @@ def qdt_tile_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
     active: jnp.ndarray | None = None,
     bands_per_image: int | None = None,
 ):
@@ -214,6 +225,7 @@ def qdt_tile_step(
     assert w % tile_w == 0 and tile_w % fuse_k == 0
     n_bands = h // band_h
     n_tiles = w // tile_w
+    n_cells = n_bands * n_tiles
     if bands_per_image is None:
         bands_per_image = n_bands
     assert n_bands % bands_per_image == 0
@@ -225,7 +237,6 @@ def qdt_tile_step(
     acc_dtype = qdt_acc_dtype(f.dtype)
     assert r.dtype == acc_dtype and d.dtype == jnp.int32
 
-    flag_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
     mid_spec = pl.BlockSpec((band_h, tile_w), lambda i, j: (i, j))
     plane = tile_specs(band_h, tile_w, fuse_k, h, w)
     kern = functools.partial(
@@ -233,19 +244,22 @@ def qdt_tile_step(
         acc_dtype=acc_dtype, bands_per_image=bands_per_image,
         n_tiles=n_tiles,
     )
-    return pl.pallas_call(
+    f2, r2, d2, changed = pl.pallas_call(
         kern,
         grid=(n_bands, n_tiles),
-        in_specs=[flag_spec, flag_spec] + plane + [mid_spec, mid_spec],
-        out_specs=[mid_spec, mid_spec, mid_spec, flag_spec],
+        in_specs=[smem_spec(), smem_spec()] + plane + [mid_spec, mid_spec],
+        out_specs=[mid_spec, mid_spec, mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((h, w), f.dtype),
             jax.ShapeDtypeStruct((h, w), acc_dtype),
             jax.ShapeDtypeStruct((h, w), jnp.int32),
-            jax.ShapeDtypeStruct((n_bands, n_tiles), jnp.int32),
+            jax.ShapeDtypeStruct((n_cells,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(base, active, *([f] * 9), r, d)
+    )(base.reshape(n_cells), active.reshape(n_cells), *([f] * 9), r, d)
+    return f2, r2, d2, changed.reshape(n_bands, n_tiles)
 
 
 def _qdt_compact_kernel(
@@ -254,28 +268,27 @@ def _qdt_compact_kernel(
 ):
     lo, hi = fuse_k, fuse_k + band_h
     cl, cr = fuse_k, fuse_k + tile_w
+    c = pl.program_id(0)
 
-    @pl.when(valid[0, 0] == 0)
+    @pl.when(valid[c] == 0)
     def _passthrough():
         f_out[...] = f_patch[lo:hi, cl:cr]
         r_out[...] = r_in[...]
         d_out[...] = d_in[...]
-        changed[...] = jnp.zeros((1, 1), jnp.int32)
+        changed[c] = 0
 
-    @pl.when(valid[0, 0] > 0)
+    @pl.when(valid[c] > 0)
     def _compute():
-        stack = f_patch[...]
+        stack = widen(f_patch[...])
         centre0 = stack[lo:hi, cl:cr]
         centre, r, d = _qdt_update(
-            stack, r_in[...], d_in[...], base[0, 0],
+            stack, r_in[...], d_in[...], base[c],
             ((lo, hi), (cl, cr)), fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre
+        f_out[...] = centre.astype(f_out.dtype)
         r_out[...] = r
         d_out[...] = d
-        changed[...] = (
-            jnp.any(centre != centre0).astype(jnp.int32).reshape(1, 1)
-        )
+        changed[c] = changed_flag(centre, centre0)
 
 
 def qdt_compact_step(
@@ -288,7 +301,8 @@ def qdt_compact_step(
     fuse_k: int,
     band_h: int,
     tile_w: int,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ):
     """Compacted-grid QDT chunk on driver-gathered active cells.
 
@@ -312,22 +326,24 @@ def qdt_compact_step(
 
     patch_spec = pl.BlockSpec((ph, tile_w + 2 * fuse_k), lambda i: (i, 0))
     mid_spec = pl.BlockSpec((band_h, tile_w), lambda i: (i, 0))
-    flag_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
 
     kern = functools.partial(
         _qdt_compact_kernel, fuse_k=fuse_k, band_h=band_h, tile_w=tile_w,
         acc_dtype=acc_dtype,
     )
-    return pl.pallas_call(
+    f2, r2, d2, changed = pl.pallas_call(
         kern,
         grid=(cap,),
-        in_specs=[flag_spec, flag_spec, patch_spec, mid_spec, mid_spec],
-        out_specs=[mid_spec, mid_spec, mid_spec, flag_spec],
+        in_specs=[smem_spec(), smem_spec(), patch_spec, mid_spec, mid_spec],
+        out_specs=[mid_spec, mid_spec, mid_spec, smem_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((cap * band_h, tile_w), f_patch.dtype),
             jax.ShapeDtypeStruct((cap * band_h, tile_w), acc_dtype),
             jax.ShapeDtypeStruct((cap * band_h, tile_w), jnp.int32),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
+            jax.ShapeDtypeStruct((cap,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(base, valid, f_patch, r_mid, d_mid)
+    )(base.reshape(cap), valid.reshape(cap), f_patch, r_mid, d_mid)
+    return f2, r2, d2, changed.reshape(cap, 1)

@@ -230,6 +230,7 @@ class ServeMetrics:
                 "degraded": b.degraded,
                 "batch_occupancy": b.occupancy,
                 "work_occupancy": b.work_occupancy,
+                "busy_chunks": b.busy_chunks,
                 "rounds": b.rounds,
                 "latency": self._percentiles(b.latencies_s),
                 "fps": fps,
@@ -261,6 +262,7 @@ class ServeMetrics:
                 "degraded": tot.degraded,
                 "batch_occupancy": tot.occupancy,
                 "work_occupancy": tot.work_occupancy,
+                "busy_chunks": tot.busy_chunks,
                 "rounds": tot.rounds,
                 "latency": self._percentiles(all_lat),
                 "fps": fps,

@@ -89,9 +89,9 @@ from repro.serve.bucketer import (BucketKey, BucketQueue, PendingRequest,
                                   check_payload, pad_fill)
 from repro.serve.cache import CacheEntry, CompiledProgramCache
 from repro.serve.continuous import SlotEngine
-from repro.serve.errors import (DeadlineExceededError, InvalidRequestError,
-                                QueueFullError, ServiceClosedError,
-                                UnsupportedDtypeError)
+from repro.serve.errors import (DeadlineExceededError, ExecutorError,
+                                InvalidRequestError, QueueFullError,
+                                ServiceClosedError, UnsupportedDtypeError)
 from repro.serve.executor import Executor
 from repro.serve.loop import EventLoop
 from repro.serve.metrics import ServeMetrics
@@ -427,7 +427,11 @@ class Service:
             timer.cancel()
         engine = self._engines.get(key)
         if engine is None and self.continuous:
-            engine = self._spawn_engine(key)
+            try:
+                engine = self._spawn_engine(key)
+            except Exception as exc:
+                self._fail_build(key, exc)
+                return
         if engine is not None:
             engine.pull()
             self._rearm_flush(key)
@@ -473,21 +477,38 @@ class Service:
     def _spawn_engine(self, key: BucketKey) -> SlotEngine | None:
         """Build the bucket's slot engine if its program is refillable
         (single convergent pallas segment); None routes to the batch
-        path.  Compile failures fall through — the batch path's ladder
-        reports them."""
+        path.  A compile failure raises to ``_launch``, which fails the
+        bucket's tickets with it (:meth:`_fail_build`)."""
         oldest = self._queue.oldest(key)
         if oldest is None or oldest.info.expr is None:
             return None
-        try:
-            entry = self._entry_for(key, oldest.info, self.max_batch,
-                                    warm=False)
-        except Exception:
-            return None
+        entry = self._entry_for(key, oldest.info, self.max_batch,
+                                warm=False)
         if entry.exe is None or not entry.exe.refillable:
             return None
         engine = SlotEngine(self, key, oldest.info, entry)
         self._engines[key] = engine
         return engine
+
+    def _fail_build(self, key: BucketKey, exc: Exception) -> None:
+        """The bucket's continuous program failed to build: every
+        request queued in the bucket gets a typed :class:`ExecutorError`
+        carrying the compile error.  No batch-path fallback — a broken
+        continuous program is a bug to see, not one to serve around."""
+        requests = self._queue.pop(key, limit=self._queue.size(key))
+        for req in requests:
+            req.ticket._queued = False
+            if req.timer is not None:
+                req.timer.cancel()
+                req.timer = None
+        self._rearm_flush(key)
+        self.metrics.count("batch_failures")
+        now = self.clock()
+        for req in requests:
+            req.ticket.error = ExecutorError(
+                f"bucket {key.label()}: continuous program failed to "
+                f"build: {exc}", cause=exc)
+            req.ticket._fulfill(now)
 
     def _shed_expired(self, requests):
         """Deadline shedding at launch: typed errors, no device time."""

@@ -90,10 +90,6 @@ def build_compressed_train_step(
     test and as a §Perf lever for collective-bound cells."""
     from jax.sharding import PartitionSpec as P
 
-    # single home for the shard_map version shim
-    from repro.core.distributed import SHMAP_KW as shmap_kw
-    from repro.core.distributed import shard_map
-
     def loss(params, batch):
         return MDL.loss_fn(params, cfg, batch, q_chunk=q_chunk)
 
@@ -123,9 +119,8 @@ def build_compressed_train_step(
             jax.tree.map(lambda _: pspec, opt_state),
             {"loss": pspec, "aux": pspec, "grad_norm": pspec, "lr": pspec},
         )
-        return shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs,
-                         **shmap_kw)(params, opt_state, batch)
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs)(params, opt_state, batch)
 
     return train_step
 

@@ -177,6 +177,12 @@ class TestPlans:
         bad = forge_plan(plan, requeue_halo=0)
         assert errors_of(A.check_plan(bad))
 
+    def test_vmem_limit_beyond_the_core_detected(self):
+        plan = plan_chain(64, 64, "uint8", 8)
+        bad = forge_plan(plan, vmem_limit_bytes=256 * 1024 * 1024)
+        errs = errors_of(A.check_plan(bad))
+        assert errs and any("vmem_limit_bytes" in f.message for f in errs)
+
     def test_shape_coverage_detected(self):
         plan = plan_chain(64, 64, "uint8", 8)
         assert errors_of(A.check_plan(plan, (1, plan.height_pad + 1,
@@ -188,8 +194,19 @@ class TestPlans:
                          height_pad=64, n_bands=4, n_chunks=1, tile_w=64)
         finds = A.check_mosaic_readiness(plan, "uint8")
         assert finds and all(f.severity == WARN for f in finds)
-        assert any("fuse_k" in f.message and "lanes wide" in f.message
-                   for f in finds)  # the PR 4 on-TPU blocker
+        # tile and halo blocks off the lane grid; K and bands off the
+        # 32-row uint8 sublane grid
+        assert {f.subject for f in finds} == {"mosaic/tile",
+                                              "mosaic/sublane"}
+
+    @pytest.mark.parametrize("dtype", ["uint8", "float32"])
+    def test_planner_plans_are_mosaic_ready(self, dtype):
+        # the plans tests/test_mosaic_compile.py compiles for v5e
+        for plan in (plan_chain(1024, 1024, dtype, 1536),
+                     plan_chain(1024, 1024, dtype, None,
+                                n_images_resident=2, n_images=4,
+                                convergent=True)):
+            assert A.check_mosaic_readiness(plan, dtype) == []
 
     def test_lane_aligned_plan_is_quiet_on_width(self):
         plan = plan_chain(64, 128, "uint8", 8)

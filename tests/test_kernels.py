@@ -6,7 +6,8 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core.chain import plan_chain
+from repro.core.chain import (DEFAULT_VMEM_BUDGET, SCOPED_VMEM_BYTES,
+                              plan_chain, working_set_bytes)
 from repro.kernels import ops, ref
 
 DTYPES = [np.uint8, np.uint16, np.float32, np.float64]
@@ -80,3 +81,23 @@ def test_plan_chain_invariants():
             assert p.width_pad % 128 == 0 and p.width_pad >= w
             assert p.height_pad % p.band_h == 0 and p.height_pad >= 777
             assert 0 < p.redundant_compute_fraction < 1
+
+
+@pytest.mark.parametrize("planes", [1, 2, 3])
+@pytest.mark.parametrize("convergent", [False, True])
+def test_plan_chain_sizes_vmem(planes, convergent):
+    """One VMEM model: the band's working set fits the budget (unless
+    the band is already one fuse_k tall) and the plan's scoped-VMEM
+    limit covers it, never below Mosaic's default."""
+    for dtype in (np.uint8, np.float32):
+        for w in (256, 1024, 2048, 5000):
+            p = plan_chain(w, w, dtype, None if convergent else 1536,
+                           n_images_resident=planes, convergent=convergent)
+            need = working_set_bytes(p.band_h, p.fuse_k, p.width_pad,
+                                     p.tile_w, dtype, planes)
+            assert need <= DEFAULT_VMEM_BUDGET or p.band_h == p.fuse_k
+            assert p.vmem_limit_bytes == max(SCOPED_VMEM_BYTES, need)
+            taller = working_set_bytes(p.band_h + p.fuse_k, p.fuse_k,
+                                       p.width_pad, p.tile_w, dtype, planes)
+            assert (taller > DEFAULT_VMEM_BUDGET or p.band_h >= 512
+                    or convergent)

@@ -22,7 +22,8 @@ from repro.core import operators as OPS
 from repro.kernels import ops as K
 from repro.serve import (AsyncService, Service, ServiceClosedError,
                          VirtualClock)
-from repro.serve.errors import DeadlineExceededError, QueueFullError
+from repro.serve.errors import (DeadlineExceededError, ExecutorError,
+                                QueueFullError)
 from repro.serve.loop import EventLoop
 from repro.serve.metrics import ServeMetrics
 
@@ -278,6 +279,47 @@ def test_continuous_refill_bit_exact(rng):
         assert t.outcome == "ok"
         ref = np.asarray(K.reconstruct(m, f, op="dilate"))
         assert_array_equal(np.asarray(t.result()), ref)
+
+
+def test_continuous_compile_failure_surfaces(rng, monkeypatch):
+    """A refillable bucket whose program fails to build fails its
+    tickets with a typed error carrying the compile error — no quiet
+    batch-path fallback, nothing raised out of the timer, nothing left
+    queued — and another bucket's flush timer due at the same instant
+    still fires."""
+    clk = VirtualClock()
+    svc = Service(continuous=True, max_batch=4, max_delay_ms=1.0,
+                  pad_quantum=16, clock=clk)
+    real_entry_for = svc._entry_for
+
+    def entry_for(key, *args, **kwargs):
+        if key.dtype == "float32":  # the reconstruct bucket only
+            raise RuntimeError("compile failed")
+        return real_entry_for(key, *args, **kwargs)
+
+    monkeypatch.setattr(svc, "_entry_for", entry_for)
+    broken = [svc.submit("reconstruct", *_recon_pair(rng))
+              for _ in range(2)]
+    img = _image(rng)
+    other = svc.submit("hfill", img)  # second bucket, same flush instant
+    clk.advance(0.002)
+    svc.poll()  # both flush timers due: neither may be lost
+    for t in broken:
+        assert t.done and t.outcome == "executor"
+        assert isinstance(t.error, ExecutorError)
+        assert "compile failed" in str(t.error.cause)
+        with pytest.raises(ExecutorError):
+            t.result()
+    for _ in range(200):
+        if other.done:
+            break
+        svc.poll()
+    assert other.outcome == "ok"
+    assert_array_equal(np.asarray(other.result()),
+                       np.asarray(OPS.hfill(jnp.asarray(img))))
+    assert not any(k.dtype == "float32" for k in svc._engines)
+    assert svc.pending() == 0
+    assert svc.stats()["counters"]["batch_failures"] == 1
 
 
 def test_continuous_matches_batch_path(rng):
