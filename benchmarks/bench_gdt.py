@@ -44,7 +44,7 @@ def run(quick: bool = True):
 
     wave = api.compile(expr, img.shape, img.dtype, "pallas")
     t = timeit(lambda: wave(img, seeds), repeats=2)
-    _, conv, busy, cap = wave.run_batch_stats(img[None], seeds[None])
+    _, conv, busy, cap, _, _ = wave.run_batch_stats(img[None], seeds[None])
     util = float(busy) / float(cap) if int(cap) else 1.0
     rows.append({
         "name": f"gdt/wavefront/{size}px",

@@ -194,8 +194,9 @@ class Executable:
         return self._run_fn(*canonical)
 
     def run_batch_stats(self, *canonical):
-        """Run phase plus the convergence watchdog's verdict and chunk
-        utilization: ``(outputs, converged, busy_chunks, cap_chunks)``.
+        """Run phase plus the convergence watchdog's verdict, chunk
+        utilization and scheduler counts: ``(outputs, converged,
+        busy_chunks, cap_chunks, compact_chunks, mask_gathers)``.
         ``converged`` is a (N,) bool vector, False for images whose
         convergence-driven segments exhausted the chunk budget
         (``ReconstructStats.converged`` per image, AND-ed across
@@ -208,7 +209,11 @@ class Executable:
         segments; both 0 when there are none) — the serving layer's
         chunk-weighted work-occupancy accounting, which exposes the
         dead capacity of early-converged slots parked behind a
-        straggler."""
+        straggler.  ``compact_chunks``/``mask_gathers`` are int32
+        scalars too: scheduler chunks that ran on the compacted grid,
+        and those of them that re-gathered the chunk-invariant operands
+        (the mask patches) because the active cell set moved (summed
+        across segments; see ``kernels.ops._drive_scheduler``)."""
         return self._run_stats_fn(*canonical)
 
     @property
@@ -315,7 +320,7 @@ class Executable:
 
             def round_(state):
                 fp, mp, *sched = state
-                fp, _, _, _, finished, sched = _scheduled_reconstruct(
+                fp, _, _, _, finished, sched, _ = _scheduled_reconstruct(
                     fp, mp, plan, op, n_chunks, False,
                     resume=tuple(sched), budget=budget)
                 return (fp, mp, *sched), finished, sched[2]
@@ -358,7 +363,7 @@ class Executable:
 
             def round_(state):
                 d, ip, sp, *sched = state
-                d, finished, sched = _scheduled_gdt(
+                d, finished, sched, _ = _scheduled_gdt(
                     d, ip, sp, plan, lamb, n_chunks,
                     resume=tuple(sched), budget=budget)
                 return (d, ip, sp, *sched), finished, sched[2]
@@ -388,7 +393,7 @@ class Executable:
 
             def round_(state):
                 x, r, d, *sched = state
-                x, r, d, finished, sched = _scheduled_qdt(
+                x, r, d, finished, sched, _ = _scheduled_qdt(
                     x, plan, n_chunks, rp=r, dp=d,
                     resume=tuple(sched), budget=budget)
                 return (x, r, d, *sched), finished, sched[2]
@@ -446,6 +451,17 @@ class Executable:
             "chunk_budget_qdt": (self._max_chunks_qdt
                                  if self.plan is not None else None),
         }
+
+    def compiled_text(self) -> str:
+        """HLO text of the compiled :meth:`run_batch_stats` program at
+        this executable's shapes.  It is lowered and compiled anew; with
+        the persistent compilation cache enabled the compile finds the
+        program the calls ran, so its instruction names match theirs."""
+        shape = ((self.height, self.width) if self.was_2d
+                 else (self.n_images, self.height, self.width))
+        arg = jax.ShapeDtypeStruct(shape, self.dtype)
+        args = [arg] * len(self.program.run_input_slots)
+        return self._run_stats_fn.lower(*args).compile().as_text()
 
     def __repr__(self):
         return (f"Executable({self.program.sig_label()}, "
@@ -510,23 +526,22 @@ class Executable:
         return self._run_padded(canonical)
 
     def _run_segments_stats(self, *canonical):
-        """Run phase + (N,) convergence vector + chunk utilization
-        (see run_batch_stats)."""
+        """Run phase + (N,) convergence vector + chunk utilization and
+        scheduler counts (see run_batch_stats)."""
         all_ok = jnp.ones((self.n_images,), jnp.bool_)
         zero = jnp.zeros((), jnp.int32)
         if self.plan is None:
             # the jnp oracle bodies iterate to their own fixpoint
-            return self._run_xla(canonical), all_ok, zero, zero
+            return self._run_xla(canonical), all_ok, zero, zero, zero, zero
         conv: list = []
         util: list = []
         outs = self._run_padded(canonical, conv, util)
         for vec in conv:
             all_ok = jnp.logical_and(all_ok, vec)
-        busy, cap = zero, zero
-        for b, c in util:
-            busy = busy + b
-            cap = cap + c
-        return outs, all_ok, busy, cap
+        sums = [zero] * 4
+        for u in util:
+            sums = [a + b for a, b in zip(sums, u)]
+        return (outs, all_ok, *sums)
 
     # -- xla engine: the jnp oracle bodies, unpadded -----------------------
 
@@ -680,7 +695,7 @@ class Executable:
                 vals[seg.srcs[0]], vals[seg.srcs[1]],
                 seg.param("op"), seg.param("n"), plan)
         elif seg.kind == "reconstruct":
-            out, it, _, _, img_conv, state = _scheduled_reconstruct(
+            out, it, _, _, img_conv, state, counts = _scheduled_reconstruct(
                 vals[seg.srcs[0]], vals[seg.srcs[1]], plan,
                 seg.param("op"), self._budget_rec(plan), False,
             )
@@ -692,16 +707,17 @@ class Executable:
                 # chunks the batch held every slot for (chunk-weighted
                 # work occupancy — parked converged slots are waste)
                 util.append((jnp.sum(state[1]),
-                             it * jnp.int32(plan.n_images)))
+                             it * jnp.int32(plan.n_images), *counts))
         elif seg.kind == "qdt":
-            _, r, d, img_conv, state = _scheduled_qdt(
+            _, r, d, img_conv, state, counts = _scheduled_qdt(
                 vals[seg.srcs[0]], plan, self._budget_qdt(plan))
             vals[seg.dsts[0]], vals[seg.dsts[1]] = d, r
             if conv is not None:
                 conv.append(img_conv)
             if util is not None:
                 util.append((jnp.sum(state[1]),
-                             jnp.max(state[1]) * jnp.int32(plan.n_images)))
+                             jnp.max(state[1]) * jnp.int32(plan.n_images),
+                             *counts))
         elif seg.kind == "gdt":
             d0, ip, sp = gdt_stage(vals[seg.srcs[0]], vals[seg.srcs[1]],
                                    seg.param("nu"))
@@ -713,14 +729,15 @@ class Executable:
                     # the sweeps run every image every round — full
                     # occupancy by construction, no parked-slot slack
                     swept = rounds * jnp.int32(plan.n_images)
-                    util.append((swept, swept))
+                    none = jnp.int32(0)
+                    util.append((swept, swept, none, none))
             else:
-                d, img_conv, state = _scheduled_gdt(
+                d, img_conv, state, counts = _scheduled_gdt(
                     d0, ip, sp, plan, seg.param("lamb"), budget)
                 if util is not None:
                     util.append((jnp.sum(state[1]),
                                  jnp.max(state[1])
-                                 * jnp.int32(plan.n_images)))
+                                 * jnp.int32(plan.n_images), *counts))
             vals[seg.dsts[0]] = d
             if conv is not None:
                 conv.append(img_conv)

@@ -30,6 +30,15 @@ def smem_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def kernel_name(name: str) -> dict:
+    """``pallas_call`` keyword arguments that name a kernel: ``name=``
+    titles the Mosaic kernel, and ``metadata={"kernel": name}`` lands in
+    the custom call's ``kernel_metadata`` frontend attribute, which a
+    TPU profiler trace keeps in each launch's event text (the trace has
+    no other name for a kernel)."""
+    return {"name": name, "metadata": {"kernel": name}}
+
+
 def changed_flag(new, old):
     """1 iff any element of ``new`` differs from ``old`` (int32 scalar)."""
     return jnp.max((new != old).astype(jnp.int32))

@@ -40,7 +40,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (elementary_3x3, fused_steps, ident_for,
-                                  image_edges, row_specs, widen)
+                                  image_edges, kernel_name, row_specs, widen)
+
+#: Name of the kernel (``pallas_call`` ``name=`` and ``kernel_metadata``).
+ROW_KERNEL = "erode_row"
 
 
 def _chain_kernel(x_top, x_mid, x_bot, out, *, op: str, fuse_k: int,
@@ -96,4 +99,5 @@ def chain_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(ROW_KERNEL),
     )(x, x, x)

@@ -46,8 +46,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (assemble_tile, changed_flag, fused_steps,
-                                  image_edges, row_specs, smem_spec,
-                                  tile_edges, tile_specs)
+                                  image_edges, kernel_name, row_specs,
+                                  smem_spec, tile_edges, tile_specs)
+
+#: Names of the row-band, tile and compact kernels (``pallas_call``
+#: ``name=`` and ``kernel_metadata``).
+ROW_KERNEL = "gdt_row"
+TILE_KERNEL = "gdt_tile"
+COMPACT_KERNEL = "gdt_compact"
 
 #: Absorbing halo/pad identities per plane.
 D_IDENT = jnp.inf    # distance: +inf never wins a min
@@ -199,6 +205,7 @@ def gdt_chain_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(ROW_KERNEL),
     )(active.reshape(n_bands), d, d, d, i, i, i, s, s, s)
     return d2, changed.reshape(n_bands, 1)
 
@@ -288,6 +295,7 @@ def gdt_tile_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(TILE_KERNEL),
     )(active.reshape(n_bands * n_tiles), *([d] * 9), *([i] * 9), *([s] * 9))
     return d2, changed.reshape(n_bands, n_tiles)
 
@@ -362,5 +370,6 @@ def gdt_compact_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(COMPACT_KERNEL),
     )(valid.reshape(cap), d_patch, i_patch, s_patch)
     return d2, changed.reshape(cap, 1)

@@ -52,11 +52,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (assemble_tile, changed_flag,
-                                  elementary_3x3, fused_steps, ident_for,
-                                  image_edges, row_specs, smem_spec,
+from repro.kernels.common import (assemble_tile, changed_flag, elementary_3x3,
+                                  fused_steps, ident_for, image_edges,
+                                  kernel_name, row_specs, smem_spec,
                                   tile_edges, tile_specs, widen)
 
+#: Names of the row-band, tile and compact kernels (``pallas_call``
+#: ``name=`` and ``kernel_metadata``).
+ROW_KERNEL = "geodesic_row"
+TILE_KERNEL = "geodesic_tile"
+COMPACT_KERNEL = "geodesic_compact"
 
 
 def _geodesic_steps(stack, mask, op: str, fuse_k: int):
@@ -154,6 +159,7 @@ def geodesic_chain_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(ROW_KERNEL),
     )(active.reshape(n_bands), f, f, f, m, m, m)
     return out, changed.reshape(n_bands, 1)
 
@@ -242,6 +248,7 @@ def geodesic_tile_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(TILE_KERNEL),
     )(active.reshape(n_bands * n_tiles), *([f] * 9), *([m] * 9))
     return out, changed.reshape(n_bands, n_tiles)
 
@@ -317,5 +324,6 @@ def geodesic_compact_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(COMPACT_KERNEL),
     )(valid.reshape(cap), f_patch, m_patch)
     return out, changed.reshape(cap, 1)

@@ -201,12 +201,30 @@ def _plan_for(f3: jnp.ndarray, plan: ChainPlan | None) -> None:
 # active-cell bookkeeping (cell = row band × column tile; n_tiles may be 1)
 # ---------------------------------------------------------------------------
 
+#: ``jax.named_scope`` names the scheduler's XLA steps carry into the
+#: compiled program's ``op_name`` metadata, so that a fusion can be told
+#: apart by its scope (``Service.op_scopes``): the compaction's patch and
+#: centre-window gathers, its scatters, and the activity bookkeeping.
+SCOPES = ("compact_gather", "compact_scatter", "schedule")
+
+
+def _scoped(scope: str):
+    """Trace the decorated function under ``jax.named_scope(scope)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
 
 def _cell_tile_w(plan: ChainPlan) -> int:
     """Pixel width of one scheduling cell (full width for row-only)."""
     return plan.tile_w or plan.width_pad
 
 
+@_scoped("schedule")
 def _dilate_active(flags: jnp.ndarray, plan: ChainPlan) -> jnp.ndarray:
     """Requeue set from changed flags: a cell is active next chunk iff it
     or a Chebyshev neighbour (vertical within the same image, horizontal
@@ -226,6 +244,7 @@ def _dilate_active(flags: jnp.ndarray, plan: ChainPlan) -> jnp.ndarray:
     return a.reshape(plan.total_bands, plan.n_tiles)
 
 
+@_scoped("compact_gather")
 def _gather_patches(x2: jnp.ndarray, idx: jnp.ndarray, plan: ChainPlan, ident):
     """Gather (band_h+2K, tile_w+2K) halo patches for flat cell indices
     ``idx`` from a stacked (TOTAL_H, W) array → (C·(band_h+2K),
@@ -259,6 +278,7 @@ def _cell_view(x2: jnp.ndarray, plan: ChainPlan) -> jnp.ndarray:
             .transpose(0, 2, 1, 3).reshape(-1, bh, tw))
 
 
+@_scoped("compact_gather")
 def _gather_mid(x2: jnp.ndarray, idx: jnp.ndarray,
                 plan: ChainPlan) -> jnp.ndarray:
     """Gather the centre windows of cells ``idx`` → (C·band_h, tile_w)."""
@@ -266,6 +286,7 @@ def _gather_mid(x2: jnp.ndarray, idx: jnp.ndarray,
     return cells.reshape(-1, _cell_tile_w(plan))
 
 
+@_scoped("compact_scatter")
 def _scatter_mid(
     x2: jnp.ndarray, idx: jnp.ndarray, new_mid: jnp.ndarray, plan: ChainPlan
 ) -> jnp.ndarray:
@@ -278,6 +299,7 @@ def _scatter_mid(
             .transpose(0, 2, 1, 3).reshape(x2.shape))
 
 
+@_scoped("compact_scatter")
 def _scatter_flags(ch: jnp.ndarray, idx: jnp.ndarray, plan: ChainPlan):
     """Workspace-slot changed flags → full (total_bands, n_tiles) grid."""
     flat = jnp.zeros((plan.total_tiles,), jnp.int32)
@@ -285,6 +307,7 @@ def _scatter_flags(ch: jnp.ndarray, idx: jnp.ndarray, plan: ChainPlan):
     return flat.reshape(plan.total_bands, plan.n_tiles)
 
 
+@_scoped("schedule")
 def _active_indices(active: jnp.ndarray, plan: ChainPlan):
     """Dense slot → flat cell index map for the compact workspace."""
     total = plan.total_tiles
@@ -505,7 +528,11 @@ def _drive_scheduler(
         cells does not re-gather the mask every chunk.
 
     Returns (data, chunks, active_cell_sum, active_per_chunk,
-    img_converged, state).  ``img_converged`` is the convergence
+    img_converged, state, counts).  ``counts`` is the int32 pair
+    ``(compact_chunks, mask_gathers)``: the chunks that took the compact
+    branch, and those of them whose ``gather_const`` cache missed (0
+    without a cache), carried as two scalars whatever ``with_stats``
+    says.  ``img_converged`` is the convergence
     watchdog's per-image verdict — a (n_images,) bool vector, True
     where the image's cells all went inactive *within the chunk
     budget*.  The loop already refuses to spin (``it < max_chunks`` in
@@ -564,31 +591,35 @@ def _drive_scheduler(
 
     def body(state):
         (data, active, it, img_chunks, asum, per_chunk, ckey, cval,
-         exhausted) = state
+         exhausted, compacted, gathers) = state
         count = jnp.sum(active)
         base = jnp.repeat(img_chunks * plan.fuse_k, plan.n_bands)[:, None]
 
         def do_full(data, ckey, cval):
             out, flags = full_step(data, active, base)
-            return out, flags, ckey, cval
+            return out, flags, ckey, cval, jnp.int32(0)
 
         def do_compact(data, ckey, cval):
             idx, valid = _active_indices(active, plan)
+            missed = jnp.int32(0)
             if with_cache:
+                hit = jnp.all(idx == ckey)
                 cval = jax.lax.cond(
-                    jnp.all(idx == ckey), lambda: cval,
-                    lambda: gather_const(idx),
+                    hit, lambda: cval, lambda: gather_const(idx),
                 )
                 ckey = idx
+                missed = jnp.logical_not(hit).astype(jnp.int32)
             out, flags = compact_step(data, idx, valid, cval, base)
-            return out, flags, ckey, cval
+            return out, flags, ckey, cval, missed
 
         if use_compact:
-            data, flags, ckey, cval = jax.lax.cond(
+            data, flags, ckey, cval, missed = jax.lax.cond(
                 count <= cap, do_compact, do_full, data, ckey, cval
             )
+            compacted = compacted + (count <= cap).astype(jnp.int32)
+            gathers = gathers + missed
         else:
-            data, flags, ckey, cval = do_full(data, ckey, cval)
+            data, flags, ckey, cval, _ = do_full(data, ckey, cval)
         if with_stats:
             per_chunk = per_chunk.at[it].set(count)
         next_active = _dilate_active(flags, plan)
@@ -613,6 +644,8 @@ def _drive_scheduler(
             ckey,
             cval,
             exhausted,
+            compacted,
+            gathers,
         )
 
     active0, img_chunks0, exhausted0 = (
@@ -627,12 +660,14 @@ def _drive_scheduler(
         key0,
         val0,
         exhausted0,
+        jnp.asarray(0, jnp.int32),
+        jnp.asarray(0, jnp.int32),
     )
     (data, active, it, img_chunks, asum, per_chunk, _, _,
-     exhausted) = jax.lax.while_loop(cond, body, init)
+     exhausted, compacted, gathers) = jax.lax.while_loop(cond, body, init)
     img_converged = jnp.logical_not(img_active(active))
     return (data, it, asum, per_chunk, img_converged,
-            (active, img_chunks, exhausted))
+            (active, img_chunks, exhausted), (compacted, gathers))
 
 
 def _scheduled_reconstruct(fp, mp, plan: ChainPlan, op: str, max_chunks: int,
@@ -709,7 +744,7 @@ def _reconstruct_impl(f, m, op, backend, max_chunks, plan, with_stats=False):
     fp = _stacked(_pad(f3, plan, ident))
     mp = _stacked(_pad(m3, plan, ident))
 
-    out, chunks, asum, per_chunk, img_conv, _ = _scheduled_reconstruct(
+    out, chunks, asum, per_chunk, img_conv, _, _ = _scheduled_reconstruct(
         fp, mp, plan, op, max_chunks, with_stats
     )
     stats = ReconstructStats(
@@ -797,8 +832,9 @@ def _scheduled_qdt(fp, plan: ChainPlan, max_chunks: int, rp=None, dp=None,
 
     ``fp`` is the stacked (TOTAL_H, W_pad) image, padded with the
     erosion identity.  Returns the final (eroded, residual, distance)
-    stacked planes plus the watchdog's per-image convergence vector and
-    the resumable scheduler state; the residual accumulator dtype
+    stacked planes plus the watchdog's per-image convergence vector, the
+    resumable scheduler state and the driver's ``(compact_chunks,
+    mask_gathers)`` counts; the residual accumulator dtype
     follows the paper's convention (float32 for float images, int32
     otherwise).  ``rp``/``dp`` accept mid-flight residual/distance
     planes (with ``resume``/``budget``) for bounded continuous-batching
@@ -853,11 +889,11 @@ def _scheduled_qdt(fp, plan: ChainPlan, max_chunks: int, rp=None, dp=None,
         d = _scatter_mid(d, idx, d2, plan)
         return (x, r, d), _scatter_flags(ch, idx, plan)
 
-    (x, r, d), _, _, _, img_conv, state = _drive_scheduler(
+    (x, r, d), _, _, _, img_conv, state, counts = _drive_scheduler(
         plan, (fp, rp, dp), full_step=full_step, compact_step=compact_step,
         max_chunks=max_chunks, resume=resume, budget=budget,
     )
-    return x, r, d, img_conv, state
+    return x, r, d, img_conv, state, counts
 
 
 def qdt_planes(
@@ -919,9 +955,9 @@ def _scheduled_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_chunks: int,
     :func:`gdt_stage`.  Only the distance plane evolves; the image and
     seed planes are chunk-invariant, so their compact-workspace patches
     go through the driver's ``gather_const`` cache as one pytree.
-    Returns (d, img_converged, state) — the same resumable contract as
-    ``_scheduled_qdt``, which is what lets ``Executable.slot_session``
-    refill gdt slots mid-flight.
+    Returns (d, img_converged, state, counts) — the same resumable
+    contract as ``_scheduled_qdt``, which is what lets
+    ``Executable.slot_session`` refill gdt slots mid-flight.
     """
     k = plan.fuse_k
 
@@ -956,12 +992,12 @@ def _scheduled_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_chunks: int,
         d = _scatter_mid(d, idx, new_mid, plan)
         return d, _scatter_flags(ch, idx, plan)
 
-    d, _, _, _, img_conv, state = _drive_scheduler(
+    d, _, _, _, img_conv, state, counts = _drive_scheduler(
         plan, dp, full_step=full_step, compact_step=compact_step,
         gather_const=gather_const, max_chunks=max_chunks,
         resume=resume, budget=budget,
     )
-    return d, img_conv, state
+    return d, img_conv, state, counts
 
 
 def _shift_row(x: jnp.ndarray, dx: int, fill):

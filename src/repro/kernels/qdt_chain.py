@@ -29,11 +29,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (assemble_tile, changed_flag,
-                                  elementary_3x3, ident_for, image_edges,
+from repro.kernels.common import (assemble_tile, changed_flag, elementary_3x3,
+                                  ident_for, image_edges, kernel_name,
                                   qdt_acc_dtype, row_specs, smem_spec,
                                   tile_edges, tile_specs, widen)
 
+#: Names of the row-band, tile and compact kernels (``pallas_call``
+#: ``name=`` and ``kernel_metadata``).
+ROW_KERNEL = "qdt_row"
+TILE_KERNEL = "qdt_tile"
+COMPACT_KERNEL = "qdt_compact"
 
 
 def _qdt_update(stack, r, d, j0, window, *, fuse_k: int, acc_dtype):
@@ -157,6 +162,7 @@ def qdt_chain_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(ROW_KERNEL),
     )(base.reshape(n_bands), active.reshape(n_bands), f, f, f, r, d)
     return f2, r2, d2, changed.reshape(n_bands, 1)
 
@@ -258,6 +264,7 @@ def qdt_tile_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(TILE_KERNEL),
     )(base.reshape(n_cells), active.reshape(n_cells), *([f] * 9), r, d)
     return f2, r2, d2, changed.reshape(n_bands, n_tiles)
 
@@ -345,5 +352,6 @@ def qdt_compact_step(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        **kernel_name(COMPACT_KERNEL),
     )(base.reshape(cap), valid.reshape(cap), f_patch, r_mid, d_mid)
     return f2, r2, d2, changed.reshape(cap, 1)

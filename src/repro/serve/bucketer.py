@@ -110,6 +110,16 @@ class Ticket:
     tripped — the value is a partial fixpoint (see the degraded-mode
     contract in ``docs/ROBUSTNESS.md``).  ``deadline`` is the absolute
     monotonic time after which the request is shed instead of served.
+
+    Stamps, on the service's clock, in the order they are set:
+    ``t_enqueue`` (admitted), ``t_launch`` (popped from its bucket),
+    ``t_dispatch`` (its batch's program enqueued on the device),
+    ``t_ready`` (the host saw the batch's outputs ready), ``t_done``
+    (delivered: at the start of the demux, or when it failed).  A
+    recovery re-run stamps ``t_dispatch``, ``t_ready`` and
+    ``batch_id`` again; a stamp the request never reached stays
+    ``None``.  ``batch_id`` names the batch it last ran in, the
+    ``batch`` argument of that batch's ``serve.*`` spans.
     """
 
     request_id: int
@@ -121,6 +131,10 @@ class Ticket:
     degraded: bool = False
     deadline: float | None = None
     t_done: float = 0.0
+    t_launch: float | None = None
+    t_dispatch: float | None = None
+    t_ready: float | None = None
+    batch_id: int | None = None
     _service: Any = dataclasses.field(default=None, repr=False)
     _bucket_key: Any = dataclasses.field(default=None, repr=False)
     _queued: bool = dataclasses.field(default=False, repr=False)

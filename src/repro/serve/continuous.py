@@ -47,12 +47,12 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import faults as F
 from repro.serve.bucketer import BucketKey, pad_fill
+from repro.serve.metrics import span
 
 
 class SlotEngine:
@@ -95,41 +95,47 @@ class SlotEngine:
         if not free:
             return 0
         svc = self.service
-        batch = svc._queue.pop(self.key, limit=len(free))
+        batch = svc._pop(self.key, limit=len(free))
         if not batch:
             return 0
-        for req in batch:
-            req.ticket._queued = False
-            if req.timer is not None:
-                req.timer.cancel()
-                req.timer = None
         batch = svc._shed_expired(batch)
         if not batch:
             return 0
         return self._admit(batch, free)
 
     def _admit(self, batch, free) -> int:
+        """One admit wave: a batch of its own in the ``serve.launch``
+        span, each request staged (``serve.stage``) and written into its
+        slot on the device (``serve.dispatch``)."""
         svc = self.service
-        if self.state is None:
-            self.state = self.session.init()
-        try:
-            svc.faults.check("dispatch", self.key.label())
-        except Exception as exc:
-            runner = functools.partial(svc._run_sync, self.key, self.info)
-            svc.executor.recover(self.key, batch, runner, exc)
-            return 0
-        refill = self.occupied  # others still iterating → these are refills
-        for req, slot in zip(batch, free):
-            self.state = self.session.admit(
-                self.state, slot, *self._staged(req))
-            self.slots[slot] = req
-            self._t_admit[slot] = svc.clock()
-            self._prev_chunks[slot] = 0  # admit re-arms the slot counter
-            if refill:
-                svc.metrics.count("refills")
+        batch_id = svc._new_batch()
+        with span("serve.launch", batch=batch_id, n=len(batch)):
+            if self.state is None:
+                self.state = self.session.init()
+            try:
+                svc.faults.check("dispatch", self.key.label())
+            except Exception as exc:
+                runner = functools.partial(svc._run_sync, self.key,
+                                           self.info)
+                svc.executor.recover(self.key, batch, runner, exc)
+                return 0
+            refill = self.occupied  # others still iterating → refills
+            for req, slot in zip(batch, free):
+                staged = self._staged(req, batch_id)
+                with span("serve.dispatch", batch=batch_id):
+                    self.state = self.session.admit(self.state, slot,
+                                                    *staged)
+                self.slots[slot] = req
+                now = svc.clock()
+                self._t_admit[slot] = now
+                req.ticket.t_dispatch = now
+                req.ticket.batch_id = batch_id
+                self._prev_chunks[slot] = 0  # admit re-arms the counter
+                if refill:
+                    svc.metrics.count("refills")
         return len(batch)
 
-    def _staged(self, req):
+    def _staged(self, req, batch_id: int):
         """Pad one request's canonical inputs to the bucket (H, W) with
         the program's absorbing fills — byte-identical to the slice of
         the batch path's ``_stage`` stack this request would occupy."""
@@ -137,10 +143,12 @@ class SlotEngine:
         dtype = np.dtype(self.key.dtype)
         rh, rw = req.shape
         out = []
-        for j in range(self.info.n_inputs):
-            buf = np.full((h, w), pad_fill(dtype, self.info.fills[j]), dtype)
-            buf[:rh, :rw] = np.asarray(req.inputs[j])
-            out.append(jnp.asarray(buf))
+        with span("serve.stage", batch=batch_id):
+            for j in range(self.info.n_inputs):
+                buf = np.full((h, w), pad_fill(dtype, self.info.fills[j]),
+                              dtype)
+                buf[:rh, :rw] = np.asarray(req.inputs[j])
+                out.append(jnp.asarray(buf))
         return out
 
     # -- rounds ------------------------------------------------------------
@@ -149,10 +157,17 @@ class SlotEngine:
         """One scheduler round: advance every occupied slot by up to
         ``refill_quantum`` chunks, harvest finished slots, refill from
         the queue.  Returns True when any work happened; never raises
-        (failures evict the session into the recovery ladder)."""
+        (failures evict the session into the recovery ladder).  The
+        round is a ``serve.drain`` span holding ``serve.dispatch`` (the
+        round enqueued), ``serve.wait`` and the harvest's
+        ``serve.demux``."""
         occupied = [i for i, r in enumerate(self.slots) if r is not None]
         if not occupied:
             return False
+        with span("serve.drain", round=self.rounds):
+            return self._round(occupied)
+
+    def _round(self, occupied) -> bool:
         svc = self.service
         try:
             for i in occupied:
@@ -160,9 +175,11 @@ class SlotEngine:
                     raise F.InjectedFault(
                         "poison",
                         f"request {self.slots[i].ticket.request_id}")
-            self.state, finished, exhausted = self.session.round(self.state)
+            with span("serve.dispatch", round=self.rounds):
+                self.state, finished, exhausted = self.session.round(
+                    self.state)
             svc.faults.check("drain", self.key.label())
-            jax.block_until_ready(self.state)
+            t_ready = svc.executor.wait(self.key, -1, self.state)
         except Exception as exc:
             self._fail_session(exc)
             return True
@@ -183,6 +200,8 @@ class SlotEngine:
         fin = np.asarray(finished)
         exh = np.asarray(exhausted)
         done = [i for i in occupied if fin[i]]
+        for i in done:
+            self.slots[i].ticket.t_ready = t_ready
         if done:
             self._harvest(done, exh)
         self.pull()

@@ -39,6 +39,12 @@ as a typed error on the affected tickets.  Injected faults
 (``serve/faults.py`` sites ``dispatch``/``drain``) enter exactly where
 the real failures would.
 
+Host spans (``serve.metrics.span``): ``serve.dispatch`` around the
+call that enqueues a batch, ``serve.drain`` around retiring one, with
+``serve.wait`` (the host blocked in ``block_until_ready``, summed into
+``host_blocked_s``) and ``serve.demux`` inside it.  Each carries the
+batch's id, which its tickets keep as ``Ticket.batch_id``.
+
 Where this sits in the pipeline (registry → bucketer → cache →
 executor) is mapped in ``docs/ARCHITECTURE.md``.
 """
@@ -55,7 +61,7 @@ import numpy as np
 from repro.serve import faults as F
 from repro.serve.bucketer import BucketKey, PendingRequest
 from repro.serve.errors import ExecutorError, PoisonedRequestError
-from repro.serve.metrics import ServeMetrics
+from repro.serve.metrics import ServeMetrics, span
 
 
 class InflightBatch(NamedTuple):
@@ -66,7 +72,8 @@ class InflightBatch(NamedTuple):
     n_slots: int
     t_dispatch: float
     runner: Any              # sync re-execution closure (recovery ladder)
-    util: Any = None         # (busy, cap) chunk-utilization scalars, or None
+    util: Any = None         # (busy, cap, compact, gathers) scalars, or None
+    batch_id: int = -1       # the batch's id in its spans and tickets
 
 
 class Executor:
@@ -95,31 +102,59 @@ class Executor:
 
     def dispatch(self, entry, key: BucketKey,
                  requests: list[PendingRequest], n_slots: int,
-                 stacked_inputs: tuple, runner=None) -> None:
+                 stacked_inputs: tuple, runner=None,
+                 batch_id: int = -1) -> None:
         """Launch one batch (async) and retire the oldest if the
         pipeline is full.  Never raises: a trace/compile failure at the
         call enters the recovery ladder instead."""
         try:
-            outputs, conv, util = self._call_entry(entry, stacked_inputs)
+            outputs, conv, util, t_dispatch = self.enqueue(
+                entry, stacked_inputs, requests, batch_id)
         except Exception as exc:
             self.recover(key, requests, runner, exc)
             return
         self._inflight.append(InflightBatch(
             outputs=outputs, converged=conv, requests=requests, key=key,
-            n_slots=n_slots, t_dispatch=self.clock(), runner=runner,
-            util=util,
+            n_slots=n_slots, t_dispatch=t_dispatch, runner=runner,
+            util=util, batch_id=batch_id,
         ))
         while len(self._inflight) > self.depth:
             self.drain_one()
+
+    def enqueue(self, entry, stacked_inputs, requests, batch_id: int):
+        """Call the entry's program (the ``serve.dispatch`` span) and
+        stamp the requests' ``t_dispatch`` and ``batch_id`` →
+        ``(outputs, conv|None, util|None, t_dispatch)``."""
+        with span("serve.dispatch", batch=batch_id):
+            outputs, conv, util = self._call_entry(entry, stacked_inputs)
+        now = self.clock()
+        for req in requests:
+            req.ticket.t_dispatch = now
+            req.ticket.batch_id = batch_id
+        return outputs, conv, util, now
+
+    def wait(self, key: BucketKey, batch_id: int, tree) -> float:
+        """Block until ``tree`` is ready (the ``serve.wait`` span, summed
+        into the bucket's ``host_blocked_s``); returns the clock when it
+        was."""
+        t0 = self.clock()
+        try:
+            with span("serve.wait", batch=batch_id):
+                jax.block_until_ready(tree)
+        finally:
+            now = self.clock()
+            self.metrics.record_wait(key.label(), now - t0)
+        return now
 
     @staticmethod
     def _call_entry(entry, stacked_inputs):
         """Run a cache entry's primary callable →
         ``(outputs, conv|None, util|None)`` where ``util`` is the
-        ``(busy_chunks, cap_chunks)`` pair of ``run_batch_stats``."""
+        ``(busy_chunks, cap_chunks, compact_chunks, mask_gathers)``
+        scalars of ``run_batch_stats``."""
         if entry.stats_fn is not None:
-            outputs, conv, busy, cap = entry.stats_fn(*stacked_inputs)
-            return outputs, conv, (busy, cap)
+            outputs, conv, *util = entry.stats_fn(*stacked_inputs)
+            return outputs, conv, tuple(util)
         out = entry.fn(*stacked_inputs)
         return (out if isinstance(out, tuple) else (out,)), None, None
 
@@ -130,16 +165,19 @@ class Executor:
         if not self._inflight:
             return False
         batch = self._inflight.popleft()
-        try:
-            self.faults.check("drain", batch.key.label())
-            jax.block_until_ready((batch.outputs, batch.converged,
-                                   batch.util))
-        except Exception as exc:  # async execution error surfaces here
-            self.recover(batch.key, batch.requests, batch.runner, exc)
-            return True
-        self._demux(batch.key, batch.requests, batch.n_slots,
-                    batch.outputs, batch.converged, batch.t_dispatch,
-                    util=batch.util)
+        with span("serve.drain", batch=batch.batch_id):
+            try:
+                self.faults.check("drain", batch.key.label())
+                now = self.wait(batch.key, batch.batch_id,
+                                (batch.outputs, batch.converged, batch.util))
+            except Exception as exc:  # async execution error surfaces here
+                self.recover(batch.key, batch.requests, batch.runner, exc)
+                return True
+            for req in batch.requests:
+                req.ticket.t_ready = now
+            self._demux(batch.key, batch.requests, batch.n_slots,
+                        batch.outputs, batch.converged, batch.t_dispatch,
+                        util=batch.util)
         return True
 
     def drain_all(self) -> None:
@@ -150,7 +188,15 @@ class Executor:
                converged, t_dispatch: float, util=None) -> None:
         """Crop, finalize and deliver per-request results (shared by the
         async drain path, the continuous engine's harvest, and the
-        synchronous recovery re-runs)."""
+        synchronous recovery re-runs), in the ``serve.demux`` span."""
+        batch_id = requests[0].ticket.batch_id if requests else None
+        with span("serve.demux", batch=-1 if batch_id is None else batch_id,
+                  n=len(requests)):
+            self._deliver(key, requests, n_slots, outputs, converged,
+                          t_dispatch, util)
+
+    def _deliver(self, key: BucketKey, requests, n_slots: int, outputs,
+                 converged, t_dispatch: float, util) -> None:
         now = self.clock()
         conv = None if converged is None else np.asarray(converged)
         latencies = []
@@ -182,8 +228,10 @@ class Executor:
             latencies.append(now - req.ticket.t_enqueue)
             pixels += h * w
 
-        busy, cap = ((int(util[0]), int(util[1])) if util is not None
-                     else (0, 0))
+        # the scheduler's four scalars come to the host in one fetch
+        busy, cap, compact, gathers = (
+            (int(u) for u in jax.device_get(util)) if util is not None
+            else (0, 0, 0, 0))
         self.metrics.record_batch(
             key.label(),
             n_real=len(requests),
@@ -196,8 +244,9 @@ class Executor:
             n_degraded=n_degraded,
             busy_chunks=busy,
             cap_chunks=cap,
+            compact_chunks=compact,
+            mask_gathers=gathers,
         )
-        return
 
     # -- recovery ladder: retry with backoff, then bisect quarantine -------
 

@@ -14,12 +14,19 @@ O(1) memory per bucket while ``requests`` counts the full history.
 ``--json`` name → us_per_call mapping), so serving throughput lands in
 the same machine-readable perf trajectory as the kernel benchmarks.
 Every emitted field is documented in ``docs/BENCHMARKS.md``.
+
+:func:`span` is the one way the serving layer opens a host span: a
+``jax.profiler.TraceAnnotation`` on the profiler's clock, so that a
+trace of a running service holds the ``serve.*`` spans beside the
+device's operations (``docs/ARCHITECTURE.md``, "Tracing a running
+service").
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 
+import jax
 import numpy as np
 
 #: Most recent per-bucket request latencies retained for percentiles.
@@ -62,6 +69,13 @@ COUNTERS = ("rejected", "shed", "expired", "retried", "poisoned",
             "backpressure_flushes", "quantum_splits", "quantum_merges")
 
 
+def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` carrying ``ids`` (``batch=``, ``request=``,
+    ``n=``) as TraceMe arguments.  With no profiler running it costs
+    the TraceMe's activity check."""
+    return jax.profiler.TraceAnnotation(name, **ids)
+
+
 #: Distinct request shapes tracked per run signature (oldest-seen kept:
 #: deterministic, bounded).
 TRAFFIC_SHAPES = 64
@@ -100,6 +114,9 @@ class _BucketStats:
     busy_slot_rounds: int = 0  # slot-rounds spent on live requests
     busy_chunks: int = 0       # scheduler chunks spent on live images
     cap_chunks: int = 0        # chunks × slots the device was held for
+    compact_chunks: int = 0    # scheduler chunks run on the compact grid
+    mask_gathers: int = 0      # compact chunks that re-gathered the mask
+    host_blocked_s: float = 0.0    # host time inside ``serve.wait``
     t_first: float | None = None   # earliest dispatch seen
     t_last: float = 0.0            # latest drain seen
     latencies_s: collections.deque = dataclasses.field(
@@ -168,6 +185,12 @@ class ServeMetrics:
         b.t_first = t if b.t_first is None else min(b.t_first, t)
         b.t_last = max(b.t_last, t)
 
+    def record_wait(self, label: str, seconds: float) -> None:
+        """The host blocked ``seconds`` waiting for a batch or round of
+        bucket ``label`` to be ready (the ``serve.wait`` span)."""
+        b = self._buckets.setdefault(label, _BucketStats())
+        b.host_blocked_s += seconds
+
     def record_batch(
         self,
         label: str,
@@ -182,6 +205,8 @@ class ServeMetrics:
         n_degraded: int = 0,
         busy_chunks: int = 0,
         cap_chunks: int = 0,
+        compact_chunks: int = 0,
+        mask_gathers: int = 0,
     ) -> None:
         b = self._buckets.setdefault(label, _BucketStats())
         b.requests += n_real
@@ -192,6 +217,8 @@ class ServeMetrics:
         b.degraded += n_degraded
         b.busy_chunks += busy_chunks
         b.cap_chunks += cap_chunks
+        b.compact_chunks += compact_chunks
+        b.mask_gathers += mask_gathers
         b.t_first = t_dispatch if b.t_first is None else min(b.t_first,
                                                              t_dispatch)
         b.t_last = max(b.t_last, t_done)
@@ -231,6 +258,9 @@ class ServeMetrics:
                 "batch_occupancy": b.occupancy,
                 "work_occupancy": b.work_occupancy,
                 "busy_chunks": b.busy_chunks,
+                "compact_chunks": b.compact_chunks,
+                "mask_gathers": b.mask_gathers,
+                "host_blocked_s": b.host_blocked_s,
                 "rounds": b.rounds,
                 "latency": self._percentiles(b.latencies_s),
                 "fps": fps,
@@ -247,6 +277,9 @@ class ServeMetrics:
             tot.busy_slot_rounds += b.busy_slot_rounds
             tot.busy_chunks += b.busy_chunks
             tot.cap_chunks += b.cap_chunks
+            tot.compact_chunks += b.compact_chunks
+            tot.mask_gathers += b.mask_gathers
+            tot.host_blocked_s += b.host_blocked_s
             if b.t_first is not None:
                 tot.t_first = (b.t_first if tot.t_first is None
                                else min(tot.t_first, b.t_first))
@@ -263,6 +296,10 @@ class ServeMetrics:
                 "batch_occupancy": tot.occupancy,
                 "work_occupancy": tot.work_occupancy,
                 "busy_chunks": tot.busy_chunks,
+                "compact_chunks": tot.compact_chunks,
+                "mask_gathers": tot.mask_gathers,
+                "host_blocked_s": tot.host_blocked_s,
+                "span_s": tot.span_s,
                 "rounds": tot.rounds,
                 "latency": self._percentiles(all_lat),
                 "fps": fps,

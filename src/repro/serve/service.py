@@ -71,10 +71,20 @@ Deterministic fault injection (``serve/faults.py``, ``REPRO_FAULTS``)
 enters at the named sites; a Service built without ``faults=`` picks up
 the environment schedule.  The layer map this front-end sits on top of
 is documented in ``docs/ARCHITECTURE.md``.
+
+Tracing: the engine opens host spans through ``serve.metrics.span`` —
+``serve.submit`` per request; ``serve.launch`` per batch, holding
+``serve.compile`` (a compiled-program cache miss), ``serve.stage`` and
+``serve.dispatch``; and the executor's ``serve.drain`` — and stamps
+each ticket's path through it (``Ticket.t_launch`` … ``t_ready``).
+:meth:`Service.op_scopes` names the compiled programs' instructions by
+the scheduler's ``jax.named_scope``.  ``docs/ARCHITECTURE.md`` says how
+to read them in a profiler trace.
 """
 from __future__ import annotations
 
 import functools
+import re
 import time
 
 import jax
@@ -94,7 +104,7 @@ from repro.serve.errors import (DeadlineExceededError, ExecutorError,
                                 ServiceClosedError, UnsupportedDtypeError)
 from repro.serve.executor import Executor
 from repro.serve.loop import EventLoop
-from repro.serve.metrics import ServeMetrics
+from repro.serve.metrics import ServeMetrics, span
 
 
 class Service:
@@ -162,6 +172,7 @@ class Service:
         self._quantum: dict[str, int] = {}  # adaptive per-sig overrides
         self._closed = False
         self._next_id = 0
+        self._next_batch = 0
 
     # -- pinned assets -----------------------------------------------------
 
@@ -204,6 +215,10 @@ class Service:
         sheds it with :class:`DeadlineExceededError` the moment its
         deadline lapses (launch re-checks after compiling, too).
         """
+        with span("serve.submit", request=self._next_id):
+            return self._submit(op, images, params, deadline_ms)
+
+    def _submit(self, op: str, images, params, deadline_ms) -> Ticket:
         if self._closed:
             self.metrics.count("rejected")
             raise ServiceClosedError(
@@ -436,16 +451,16 @@ class Service:
             engine.pull()
             self._rearm_flush(key)
             return
-        requests = self._queue.pop(key)
-        for req in requests:
-            req.ticket._queued = False
-            if req.timer is not None:
-                req.timer.cancel()
-                req.timer = None
+        requests = self._pop(key)
         self._rearm_flush(key)  # anything beyond max_batch stays queued
         requests = self._shed_expired(requests)
         if not requests:
             return
+        batch = self._new_batch()
+        with span("serve.launch", batch=batch, n=len(requests)):
+            self._launch_batch(key, requests, batch)
+
+    def _launch_batch(self, key: BucketKey, requests, batch: int) -> None:
         info = requests[0].info
         runner = functools.partial(self._run_sync, key, info)
         n_slots = canonical_batch(len(requests), self.max_batch)
@@ -461,7 +476,7 @@ class Service:
                 requests = live
                 n_slots = canonical_batch(len(requests), self.max_batch)
                 entry = self._entry_for(key, info, n_slots, warm=False)
-            stacked = self._stage(info, key, requests, n_slots)
+            stacked = self._stage(info, key, requests, n_slots, batch)
             self.faults.check("dispatch", key.label())
             self._check_poison(requests)
         except Exception as exc:
@@ -472,7 +487,26 @@ class Service:
             self.executor.recover(key, requests, runner, exc)
             return
         self.executor.dispatch(entry, key, requests, n_slots, stacked,
-                               runner=runner)
+                               runner=runner, batch_id=batch)
+
+    def _pop(self, key: BucketKey, limit: int | None = None) -> list:
+        """Take up to ``limit`` (default ``max_batch``) requests out of
+        bucket ``key``: disarm their expiry timers and stamp
+        ``t_launch``."""
+        requests = self._queue.pop(key, limit=limit)
+        now = self.clock()
+        for req in requests:
+            req.ticket._queued = False
+            req.ticket.t_launch = now
+            if req.timer is not None:
+                req.timer.cancel()
+                req.timer = None
+        return requests
+
+    def _new_batch(self) -> int:
+        """A fresh id for a batch's spans and its tickets' ``batch_id``."""
+        self._next_batch += 1
+        return self._next_batch - 1
 
     def _spawn_engine(self, key: BucketKey) -> SlotEngine | None:
         """Build the bucket's slot engine if its program is refillable
@@ -495,12 +529,7 @@ class Service:
         request queued in the bucket gets a typed :class:`ExecutorError`
         carrying the compile error.  No batch-path fallback — a broken
         continuous program is a bug to see, not one to serve around."""
-        requests = self._queue.pop(key, limit=self._queue.size(key))
-        for req in requests:
-            req.ticket._queued = False
-            if req.timer is not None:
-                req.timer.cancel()
-                req.timer = None
+        requests = self._pop(key, limit=self._queue.size(key))
         self._rearm_flush(key)
         self.metrics.count("batch_failures")
         now = self.clock()
@@ -546,11 +575,16 @@ class Service:
         ladder: restage the given subset, run, block.  Returns
         ``(outputs, n_slots, converged)``."""
         n_slots = canonical_batch(len(requests), self.max_batch)
-        entry = self._entry_for(key, info, n_slots, warm=False)
-        stacked = self._stage(info, key, requests, n_slots)
-        self._check_poison(requests)
-        outputs, conv, _ = Executor._call_entry(entry, stacked)
-        jax.block_until_ready((outputs, conv))
+        batch = self._new_batch()
+        with span("serve.launch", batch=batch, n=len(requests)):
+            entry = self._entry_for(key, info, n_slots, warm=False)
+            stacked = self._stage(info, key, requests, n_slots, batch)
+            self._check_poison(requests)
+            outputs, conv, _, _ = self.executor.enqueue(
+                entry, stacked, requests, batch)
+            now = self.executor.wait(key, batch, (outputs, conv))
+        for req in requests:
+            req.ticket.t_ready = now
         return outputs, n_slots, conv
 
     # -- bucketing policy --------------------------------------------------
@@ -620,22 +654,28 @@ class Service:
 
     def _entry_for(self, key: BucketKey, info, n_slots: int,
                    warm: bool) -> CacheEntry:
-        """Compiled bucket program: the cache key *is* the compile key."""
+        """Compiled bucket program: the cache key *is* the compile key.
+        A miss builds the entry inside a ``serve.compile`` span; XLA
+        compiles an expression program on its first call, so a program
+        new to the process compiles in the ``serve.dispatch`` span that
+        follows."""
         lookup = self.cache.warm if warm else self.cache.get
         cache_key, exe = self._cache_identity(key, info, n_slots)
         if exe is not None:
-            return lookup(
-                cache_key,
-                lambda: CacheEntry(fn=exe.run_batch, plan=exe.plan,
-                                   key=cache_key,
-                                   stats_fn=exe.run_batch_stats, exe=exe),
-            )
-        spec = registry.get(info.sig[1])  # ("custom", name, canon)
-        return lookup(
-            cache_key,
-            functools.partial(self._build_custom, spec, info.sig[2], key,
-                              n_slots, cache_key),
-        )
+            def build():
+                return CacheEntry(fn=exe.run_batch, plan=exe.plan,
+                                  key=cache_key,
+                                  stats_fn=exe.run_batch_stats, exe=exe)
+        else:
+            spec = registry.get(info.sig[1])  # ("custom", name, canon)
+            build = functools.partial(self._build_custom, spec, info.sig[2],
+                                      key, n_slots, cache_key)
+
+        def miss():
+            with span("serve.compile"):
+                return build()
+
+        return lookup(cache_key, miss)
 
     def _build_custom(self, spec, canon: tuple, key: BucketKey,
                       n_slots: int, cache_key: tuple) -> CacheEntry:
@@ -651,20 +691,23 @@ class Service:
 
         return CacheEntry(fn=jax.jit(call), plan=plan, key=cache_key)
 
-    def _stage(self, info, key: BucketKey, requests, n_slots: int) -> tuple:
+    def _stage(self, info, key: BucketKey, requests, n_slots: int,
+               batch: int = -1) -> tuple:
         """Host staging: pad each canonical input to the bucket shape and
         stack; sentinel slots keep the absorbing fill (they converge in
-        one chunk under the active-tile scheduler)."""
+        one chunk under the active-tile scheduler).  The ``serve.stage``
+        span of batch ``batch``."""
         h, w = key.hw
         dtype = np.dtype(key.dtype)
         stacked = []
-        for j in range(info.n_inputs):
-            buf = np.full((n_slots, h, w), pad_fill(dtype, info.fills[j]),
-                          dtype)
-            for i, req in enumerate(requests):
-                rh, rw = req.shape
-                buf[i, :rh, :rw] = np.asarray(req.inputs[j])
-            stacked.append(jnp.asarray(buf))
+        with span("serve.stage", batch=batch):
+            for j in range(info.n_inputs):
+                buf = np.full((n_slots, h, w),
+                              pad_fill(dtype, info.fills[j]), dtype)
+                for i, req in enumerate(requests):
+                    rh, rw = req.shape
+                    buf[i, :rh, :rw] = np.asarray(req.inputs[j])
+                stacked.append(jnp.asarray(buf))
         return tuple(stacked)
 
     # -- warm-up + introspection ------------------------------------------
@@ -719,6 +762,28 @@ class Service:
         out["faults"] = self.faults.snapshot()
         return out
 
+    def op_scopes(self) -> dict:
+        """``{HLO head → scope}`` for the instructions of every compiled
+        program in the cache whose ``op_name`` holds one of the
+        scheduler's scopes (``kernels.ops.SCOPES``).  A head is an
+        instruction's ``%name = shape opcode`` with layouts stripped,
+        plus a custom call's target or a fusion's kind: the start of
+        the instruction's event in a TPU profiler trace, which names
+        device operations by their HLO text alone.  A head that two
+        programs give different scopes maps to ``None``.  Each program
+        is lowered again at its own shapes (the persistent compilation
+        cache, when enabled, returns the program the calls ran), so
+        call this after the traffic, never while serving.  Programs of
+        custom ops (no ``Executable``) are left out."""
+        found: dict = {}
+        for entry in self.cache.entries():
+            if entry.exe is None:
+                continue
+            for head, scope in hlo_scopes(entry.exe.compiled_text()).items():
+                if found.setdefault(head, scope) != scope:
+                    found[head] = None
+        return found
+
     def bench_rows(self) -> list[dict]:
         """Rows in the benchmarks ``name,us_per_call,derived`` contract
         (per-bucket latency/throughput plus the lifecycle counters)."""
@@ -731,6 +796,49 @@ class Service:
         already past admission/launch)."""
         return len(self._queue) + sum(e.n_occupied
                                       for e in self._engines.values())
+
+
+_HLO_LAYOUT = re.compile(r"\{[^{}]*\}")
+_HLO_HEAD = re.compile(r"^%(?P<name>\S+) = (?P<shape>\([^()]*\)|\S+) "
+                       r"(?P<opcode>[a-z][\w\-]*)\(")
+_HLO_TARGET = re.compile(r'custom_call_target="([^"]+)"')
+_HLO_KIND = re.compile(r"kind=(k\w+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_head(text: str) -> str | None:
+    """The head of one HLO instruction's text: ``%name = shape opcode``
+    with layouts stripped, then a custom call's target or a fusion's
+    kind; ``None`` for a line that is no instruction."""
+    m = _HLO_HEAD.match(_HLO_LAYOUT.sub("", text))
+    if m is None:
+        return None
+    head = f"%{m['name']} = {m['shape']} {m['opcode']}"
+    target = _HLO_TARGET.search(text)
+    kind = _HLO_KIND.search(text) if m["opcode"] == "fusion" else None
+    if target or kind:
+        head += f" {target.group(1) if target else kind.group(1)}"
+    return head
+
+
+def hlo_scopes(text: str, scopes=None) -> dict:
+    """``{head → scope}`` for each instruction of an HLO module's text
+    whose ``op_name`` holds one of ``scopes`` (default: the scheduler's,
+    ``kernels.ops.SCOPES``) as a path component; the innermost wins."""
+    if scopes is None:
+        from repro.kernels.ops import SCOPES as scopes
+    out = {}
+    for line in text.splitlines():
+        name = _HLO_OP_NAME.search(line)
+        if name is None:
+            continue
+        held = [p for p in name.group(1).split("/") if p in scopes]
+        if not held:
+            continue
+        head = hlo_head(line.strip().removeprefix("ROOT "))
+        if head is not None:
+            out[head] = held[-1]
+    return out
 
 
 class AsyncService:

@@ -6,7 +6,9 @@ for a topology that is described, not attached.  Each test lowers one
 ``pallas_call`` at the plan ``repro.api.compile`` makes for the served
 expression, compiles it, and checks that the program holds the Mosaic
 kernel (``tpu_custom_call``) — so a block layout, dtype or VMEM budget
-the chip's compiler refuses fails here, not on the chip.
+the chip's compiler refuses fails here, not on the chip — and that the
+launch names its own kernel in ``kernel_metadata``, the one name a TPU
+profiler trace keeps for it.
 
 The topology is described inside a module-scoped fixture (never while
 the module is imported): only one process at a time may load the TPU
@@ -15,6 +17,7 @@ library, and every test worker imports this file.
 import dataclasses
 import functools
 import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +30,9 @@ from repro.kernels import erode_chain, gdt_chain, geodesic_chain, qdt_chain
 from repro.kernels.common import qdt_acc_dtype
 
 SIZE = 1024
+
+#: A kernel's name as the compiled program prints ``kernel_metadata``.
+KERNEL_NAME = re.compile(r'kernel_metadata=\{\s*"kernel"\s*:\s*"(\w+)"')
 
 
 @pytest.fixture(scope="module")
@@ -228,7 +234,10 @@ CASES = [
 def test_kernel_compiles_for_v5e(one_chip, name, dtype, n):
     build, _ = KERNELS[name]
     compiled = build(one_chip, jnp.dtype(dtype), n, SIZE)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each of the ten kernels carries its own, distinct name
+    assert set(KERNEL_NAME.findall(text)) == {name}
 
 
 @pytest.mark.parametrize("name,dtype", [
