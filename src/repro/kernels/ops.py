@@ -245,29 +245,45 @@ def _dilate_active(flags: jnp.ndarray, plan: ChainPlan) -> jnp.ndarray:
 
 
 @_scoped("compact_gather")
-def _gather_patches(x2: jnp.ndarray, idx: jnp.ndarray, plan: ChainPlan, ident):
-    """Gather (band_h+2K, tile_w+2K) halo patches for flat cell indices
-    ``idx`` from a stacked (TOTAL_H, W) array → (C·(band_h+2K),
-    tile_w+2K).  Rows outside the cell's *image* and columns outside the
-    array are pinned to ``ident`` here, since the compact kernel cannot
-    know slot → image geometry.  Sentinel slots (idx == total_tiles)
-    come back all-``ident`` (their output is dropped at scatter)."""
+def _halo_view(x2: jnp.ndarray, plan: ChainPlan, ident) -> jnp.ndarray:
+    """Stacked (TOTAL_H, W) → per-image (N, H_pad + 2K, W + 2K) view,
+    ringed by ``fuse_k`` rows and columns of ``ident`` around each
+    image.  A cell's halo patch is then one rectangle of this view whose
+    corner sits on a band and tile boundary, and rows outside the cell's
+    image and columns outside the array read ``ident`` by construction —
+    the pinning the compact kernels cannot do, since they know nothing
+    of slot → image geometry."""
+    k = plan.fuse_k
+    x3 = x2.reshape(plan.n_images, plan.height_pad, x2.shape[1])
+    return jnp.pad(x3, ((0, 0), (k, k), (k, k)), constant_values=ident)
+
+
+@_scoped("compact_gather")
+def _gather_patches(view: jnp.ndarray, idx: jnp.ndarray, plan: ChainPlan,
+                    ident):
+    """Copy the (band_h+2K, tile_w+2K) halo patches of flat cell indices
+    ``idx`` out of a :func:`_halo_view` → (C·(band_h+2K), tile_w+2K):
+    one window a workspace slot, at the cell's band and tile offset in
+    its image's view.  Sentinel slots (idx == total_tiles) come back
+    all-``ident`` (their output is dropped at scatter).
+
+    The copies are a ``scan`` rather than a gather: the TPU compiler
+    expands a windowed gather into a loop that keeps no ``op_name``, so
+    its time would escape the ``compact_gather`` scope."""
     bh, k, tw = plan.band_h, plan.fuse_k, _cell_tile_w(plan)
-    h, w = x2.shape
-    bi = idx // plan.n_tiles         # global band index
-    tj = idx % plan.n_tiles          # column tile index
-    rows = bi[:, None] * bh - k + jnp.arange(bh + 2 * k)[None, :]
-    cols = tj[:, None] * tw - k + jnp.arange(tw + 2 * k)[None, :]
-    img0 = (bi // plan.n_bands) * plan.height_pad
-    row_ok = (rows >= img0[:, None]) & (rows < img0[:, None] + plan.height_pad)
-    col_ok = (cols >= 0) & (cols < w)
-    g = jnp.take(x2, jnp.clip(rows, 0, h - 1), axis=0)
-    g = jnp.take_along_axis(
-        g, jnp.broadcast_to(jnp.clip(cols, 0, w - 1)[:, None, :],
-                            (idx.shape[0], bh + 2 * k, tw + 2 * k)),
-        axis=2,
-    )
-    g = jnp.where(row_ok[:, :, None] & col_ok[:, None, :], g, ident)
+    real = idx < plan.total_tiles
+    cell = jnp.where(real, idx, 0)
+    bi = cell // plan.n_tiles        # global band index
+    starts = jnp.stack([bi // plan.n_bands, (bi % plan.n_bands) * bh,
+                        (cell % plan.n_tiles) * tw], axis=1)
+
+    def window(_, start):
+        win = jax.lax.dynamic_slice(view, tuple(start),
+                                    (1, bh + 2 * k, tw + 2 * k))
+        return None, win[0]
+
+    _, g = jax.lax.scan(window, None, starts)
+    g = jnp.where(real[:, None, None], g, jnp.asarray(ident, view.dtype))
     return g.reshape(-1, tw + 2 * k)
 
 
@@ -576,9 +592,13 @@ def _drive_scheduler(
 
     if with_cache:
         # A never-matching key forces a gather on the first compact
-        # chunk; the initial value only fixes the cache pytree's shapes.
+        # chunk, so no chunk reads the initial value: it is built from
+        # the cache pytree's shapes alone.
         key0 = jnp.full((cap,), -1, jnp.int32)
-        val0 = gather_const(jnp.full((cap,), total, jnp.int32))
+        val0 = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(gather_const, key0),
+        )
     else:
         key0, val0 = jnp.zeros((0,), jnp.int32), ()
 
@@ -700,11 +720,14 @@ def _scheduled_reconstruct(fp, mp, plan: ChainPlan, op: str, max_chunks: int,
             bands_per_image=plan.n_bands,
         )
 
+    m_view = _halo_view(mp, plan, ident)
+
     def gather_const(idx):
-        return _gather_patches(mp, idx, plan, ident)
+        return _gather_patches(m_view, idx, plan, ident)
 
     def compact_step(x, idx, valid, mask_patch, base):
-        f_patch = _gather_patches(x, idx, plan, ident)
+        f_patch = _gather_patches(_halo_view(x, plan, ident), idx, plan,
+                                  ident)
         new_mid, ch = geodesic_compact_step(
             f_patch, mask_patch, valid,
             op=op, fuse_k=plan.fuse_k, band_h=plan.band_h,
@@ -871,7 +894,8 @@ def _scheduled_qdt(fp, plan: ChainPlan, max_chunks: int, rp=None, dp=None,
 
     def compact_step(data, idx, valid, const, base):
         x, r, d = data
-        f_patch = _gather_patches(x, idx, plan, ident)
+        f_patch = _gather_patches(_halo_view(x, plan, ident), idx, plan,
+                                  ident)
         rm = _gather_mid(r, idx, plan)
         dm = _gather_mid(d, idx, plan)
         # per-slot distance offset: each gathered cell carries its own
@@ -976,13 +1000,17 @@ def _scheduled_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_chunks: int,
             bands_per_image=plan.n_bands,
         )
 
+    i_view = _halo_view(ip, plan, I_IDENT)
+    s_view = _halo_view(sp, plan, S_IDENT)
+
     def gather_const(idx):
-        return (_gather_patches(ip, idx, plan, I_IDENT),
-                _gather_patches(sp, idx, plan, S_IDENT))
+        return (_gather_patches(i_view, idx, plan, I_IDENT),
+                _gather_patches(s_view, idx, plan, S_IDENT))
 
     def compact_step(d, idx, valid, const, base):
         i_patch, s_patch = const
-        d_patch = _gather_patches(d, idx, plan, D_IDENT)
+        d_patch = _gather_patches(_halo_view(d, plan, D_IDENT), idx, plan,
+                                  D_IDENT)
         new_mid, ch = gdt_compact_step(
             d_patch, i_patch, s_patch, valid,
             lamb=lamb, fuse_k=k, band_h=plan.band_h,

@@ -6,6 +6,7 @@ the Pallas driver against the pure-jnp ``core.morphology`` references —
 while the stats must show it actually skipped work on sparse markers.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -261,12 +262,10 @@ def test_batched_qdt_ragged_convergence(rng):
         np.testing.assert_array_equal(np.asarray(r[i]), np.asarray(rw))
 
 
-def test_compaction_mask_cache_exact():
-    """Wavefront confined to one band for many chunks: the compact
-    workspace's mask gather is reused between chunks (the shared
-    driver's gather_const cache hits while the active set is static);
-    the output must stay bit-exact vs the oracle."""
-    H, W = 128, 256
+def _corridor_wavefront(H=128, W=256):
+    """A marker seeded at one end of a serpentine corridor inside band 0
+    (rows 0..31): the wavefront stays in the same few cells for many
+    chunks."""
     mask = np.zeros((H, W), np.uint8)
     rows = list(range(2, 28, 4))
     for row in rows:  # serpentine corridor inside band 0 (rows 0..31)
@@ -276,12 +275,74 @@ def test_compaction_mask_cache_exact():
         mask[row : row + 6, col : col + 2] = 200
     marker = np.zeros((H, W), np.uint8)
     marker[2, 4] = 200
-    marker = np.minimum(marker, mask)
+    return np.minimum(marker, mask), mask
+
+
+def test_compaction_mask_cache_exact():
+    """Wavefront confined to one band for many chunks: the compact
+    workspace's mask gather is reused between chunks (the shared
+    driver's gather_const cache hits while the active set is static);
+    the output must stay bit-exact vs the oracle."""
+    marker, mask = _corridor_wavefront()
     out, stats = ops.reconstruct_with_stats(
         jnp.asarray(marker), jnp.asarray(mask), "dilate", "pallas")
     want = M.dilate_reconstruct(jnp.asarray(marker), jnp.asarray(mask))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
     assert int(stats.chunks) > 8  # the in-band iteration actually ran long
+
+
+def test_compaction_schedule_pinned():
+    """The corridor's schedule, pinned: the full grid's 8 cells once,
+    then 55 compact chunks of 4 cells that gather the mask once and hit
+    the cache after.  How a patch is fetched must not move it."""
+    marker, mask = _corridor_wavefront()
+    _, stats = ops.reconstruct_with_stats(
+        jnp.asarray(marker), jnp.asarray(mask), "dilate", "pallas")
+    chunks = int(stats.chunks)
+    per_chunk = np.asarray(stats.active_per_chunk)
+    assert chunks == 56
+    assert per_chunk[:chunks].tolist() == [8] + [4] * 55
+    assert not per_chunk[chunks:].any()
+
+    plan = plan_chain(*mask.shape, np.uint8, None, n_images_resident=2,
+                      n_images=1, convergent=True)
+    fp = ops._stacked(ops._pad(jnp.asarray(marker)[None], plan, 0))
+    mp = ops._stacked(ops._pad(jnp.asarray(mask)[None], plan, 0))
+    out = ops._scheduled_reconstruct(fp, mp, plan, "dilate",
+                                     mask.size // plan.fuse_k + 2, False)
+    compact_chunks, mask_gathers = out[-1]
+    assert (int(compact_chunks), int(mask_gathers)) == (55, 1)
+
+
+def _eqns_outside_loops(jaxpr):
+    """Equations of ``jaxpr`` and of the jaxprs nested in them (jit,
+    cond branches), except what runs inside a ``while`` loop."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "while":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_outside_loops(sub)
+
+
+def test_compaction_no_gather_before_loop():
+    """The mask cache's first value is built from shapes alone: no chunk
+    reads it (its key never matches), so a compacting reconstruct
+    fetches patches only inside its scheduler loop.  Outside it there is
+    no gather and no other loop (a patch copy is a ``scan`` of windows)."""
+    marker, mask = _corridor_wavefront()
+    closed = jax.make_jaxpr(
+        lambda f, m: ops.reconstruct_with_stats(f, m, "dilate", "pallas")
+    )(jnp.asarray(marker), jnp.asarray(mask))
+    outside = list(_eqns_outside_loops(closed.jaxpr))
+    names = [e.primitive.name for e in outside]
+    assert "gather" not in names and "scan" not in names
+    loops = [e for e in outside if e.primitive.name == "while"]
+    assert len(loops) == 1
+    assert "scan" in str(loops[0].params["body_jaxpr"])
 
 
 def test_operators_pallas_backend(rng):
