@@ -41,7 +41,7 @@ TPU/Pallas port, one stage per module:
     compile cache uses), each entry carrying the ChainPlan it embeds.
 ``executor``
     The paper overlaps the filters of a chain across cores; the
-    executor overlaps *host staging* of the next stack with *device
+    executor overlaps *staging* of the next stack with *device
     compute* of the current one (JAX async dispatch, bounded in-flight
     depth = double buffering) and demuxes per-request results, cropping
     bucket padding and dropping sentinel slots.

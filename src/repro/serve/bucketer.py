@@ -28,6 +28,8 @@ import dataclasses
 import math
 from typing import Any, NamedTuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import morphology as M
@@ -39,6 +41,37 @@ def pad_fill(dtype, which: str):
     (the lattice top/bottom already defined by ``core.morphology``)."""
     top = which == "hi"
     return np.asarray(M.lattice_top(dtype) if top else M.lattice_bottom(dtype))
+
+
+#: One compiled stack per slot count and plane shape.
+_stack = jax.jit(jnp.stack)
+
+
+def pad_plane(plane: jax.Array, hw: tuple[int, int], dtype,
+              which: str) -> jax.Array:
+    """One canonical input padded where it is, on the device, at its
+    bottom and right to the bucket ``hw`` with the absorbing fill of
+    ``which`` (:func:`pad_fill`).  Each request shape compiles once."""
+    (rh, rw), (h, w) = plane.shape, hw
+    if (rh, rw) == (h, w):
+        return plane
+    return jnp.pad(plane, ((0, h - rh), (0, w - rw)),
+                   constant_values=pad_fill(dtype, which))
+
+
+def stage_planes(planes, n_slots: int, hw: tuple[int, int], dtype,
+                 which: str) -> jax.Array:
+    """The ``(n_slots, h, w)`` batch stack of one canonical input, built
+    on the device: each request's plane padded by :func:`pad_plane` in
+    slot order, and every slot past the last filled with the absorbing
+    fill (a sentinel).  Nothing is read back to the host, and the stack
+    compiles once per ``n_slots``, however many of its slots are
+    sentinels."""
+    padded = [pad_plane(p, hw, dtype, which) for p in planes]
+    if len(padded) < n_slots:
+        sentinel = jnp.full(hw, pad_fill(dtype, which))
+        padded += [sentinel] * (n_slots - len(padded))
+    return _stack(padded)
 
 
 def check_payload(op: str, images) -> None:

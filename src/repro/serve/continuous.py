@@ -47,11 +47,10 @@ from __future__ import annotations
 
 import functools
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import faults as F
-from repro.serve.bucketer import BucketKey, pad_fill
+from repro.serve.bucketer import BucketKey, pad_plane
 from repro.serve.metrics import span
 
 
@@ -136,20 +135,15 @@ class SlotEngine:
         return len(batch)
 
     def _staged(self, req, batch_id: int):
-        """Pad one request's canonical inputs to the bucket (H, W) with
-        the program's absorbing fills — byte-identical to the slice of
-        the batch path's ``_stage`` stack this request would occupy."""
-        h, w = self.key.hw
-        dtype = np.dtype(self.key.dtype)
-        rh, rw = req.shape
-        out = []
+        """Pad one request's canonical inputs on the device to the bucket
+        (H, W) with the program's absorbing fills — byte-identical to
+        the slice of the batch path's ``_stage`` stack this request
+        would occupy."""
+        to_device = self.service.metrics.to_device
         with span("serve.stage", batch=batch_id):
-            for j in range(self.info.n_inputs):
-                buf = np.full((h, w), pad_fill(dtype, self.info.fills[j]),
-                              dtype)
-                buf[:rh, :rw] = np.asarray(req.inputs[j])
-                out.append(jnp.asarray(buf))
-        return out
+            return [pad_plane(to_device(x), self.key.hw, self.key.dtype,
+                              fill)
+                    for x, fill in zip(req.inputs, self.info.fills)]
 
     # -- rounds ------------------------------------------------------------
 
@@ -212,7 +206,7 @@ class SlotEngine:
         request shape, finalize, fulfill) and free them."""
         svc = self.service
         outputs = self.session.extract(self.state)
-        outs = tuple(np.asarray(o)[done] for o in outputs)
+        outs = tuple(svc.metrics.to_host(o)[done] for o in outputs)
         conv = ~exh[done]  # exhausted slot → degraded partial fixpoint
         requests = [self.slots[i] for i in done]
         t0 = min(self._t_admit[i] for i in done)

@@ -1,12 +1,12 @@
-"""Async double-buffered executor: overlap host staging with device
+"""Async double-buffered executor: overlap staging with device
 compute, demux per-request results — and keep serving through failures.
 
 JAX dispatch is asynchronous — calling a compiled program enqueues the
 device work and returns device buffers immediately — so the pipeline
-falls out of bounded in-flight tracking: the service stages (pads,
-stacks, uploads) the *next* batch on the host while the device crunches
-the current one, and the executor only blocks when ``depth`` batches
-are already in flight (``depth=2`` is classic double buffering).
+falls out of bounded in-flight tracking: the service stages (pads and
+stacks, on the device) the *next* batch while the device crunches the
+current one, and the executor only blocks when ``depth`` batches are
+already in flight (``depth=2`` is classic double buffering).
 
 Draining a batch demuxes it: each real request slot is cropped back to
 its original (H, W) (dropping the pad-to-bucket canonicalization), the
@@ -55,7 +55,6 @@ import time
 from typing import Any, NamedTuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import faults as F
@@ -209,7 +208,8 @@ class Executor:
             try:
                 if req.finalize is not None:
                     cropped = tuple(req.finalize(
-                        cropped, tuple(map(jnp.asarray, req.images))))
+                        cropped,
+                        tuple(map(self.metrics.to_device, req.images))))
                 # arity per request: co-batched ops share a run phase
                 # but may fan their finalize into different output counts
                 req.ticket.value = (

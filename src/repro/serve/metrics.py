@@ -27,6 +27,7 @@ import collections
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 #: Most recent per-bucket request latencies retained for percentiles.
@@ -158,6 +159,25 @@ class ServeMetrics:
         self._buckets: dict[str, _BucketStats] = {}
         self.counters = collections.Counter()
         self.traffic: dict[str, TrafficStats] = {}
+        #: bytes of request data put on the device (submit → finalize)
+        #: and read back from it before delivery
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+
+    def to_device(self, x) -> jax.Array:
+        """``x`` on the device; a host array's bytes count into
+        ``h2d_bytes`` as it goes up."""
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+            self.h2d_bytes += x.nbytes
+        return jnp.asarray(x)
+
+    def to_host(self, x) -> np.ndarray:
+        """``x`` on the host; a device array's bytes count into
+        ``d2h_bytes`` as it comes back."""
+        if isinstance(x, jax.Array):
+            self.d2h_bytes += x.nbytes
+        return np.asarray(x)
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump one lifecycle counter (see :data:`COUNTERS`)."""
@@ -300,6 +320,9 @@ class ServeMetrics:
                 "mask_gathers": tot.mask_gathers,
                 "host_blocked_s": tot.host_blocked_s,
                 "span_s": tot.span_s,
+                "pixels": tot.pixels,
+                "h2d_bytes": self.h2d_bytes,
+                "d2h_bytes": self.d2h_bytes,
                 "rounds": tot.rounds,
                 "latency": self._percentiles(all_lat),
                 "fps": fps,

@@ -141,12 +141,13 @@ class OpSpec:
     def build_expr(self, canon: tuple):
         return self.expr_builder(dict(canon))
 
-    def prepare_inputs(self, images: tuple, params: tuple) -> tuple:
-        """Per-request prepare stage on the unpadded images."""
+    def prepare_inputs(self, images: tuple, params: tuple,
+                       put=jnp.asarray) -> tuple:
+        """Per-request prepare stage on the unpadded images; an
+        expression op's images go to the device through ``put``."""
         if self.expr_builder is not None:
             info = request_info(self.name, params)
-            env = dict(zip(info.program.input_names,
-                           (jnp.asarray(im) for im in images)))
+            env = dict(zip(info.program.input_names, map(put, images)))
             memo: dict = {}
             return tuple(eval_pointwise(e, env, {}, memo)
                          for e in info.program.prepare)

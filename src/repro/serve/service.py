@@ -88,7 +88,6 @@ import re
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import api
@@ -96,7 +95,7 @@ from repro.serve import faults as F
 from repro.serve import registry
 from repro.serve.bucketer import (BucketKey, BucketQueue, PendingRequest,
                                   Ticket, bucket_hw, canonical_batch,
-                                  check_payload, pad_fill)
+                                  check_payload, stage_planes)
 from repro.serve.cache import CacheEntry, CompiledProgramCache
 from repro.serve.continuous import SlotEngine
 from repro.serve.errors import (DeadlineExceededError, ExecutorError,
@@ -256,7 +255,9 @@ class Service:
         self._next_id += 1
         req = PendingRequest(
             ticket=ticket, images=imgs,
-            inputs=spec.prepare_inputs(imgs, canon), shape=imgs[0].shape,
+            inputs=spec.prepare_inputs(imgs, canon,
+                                       put=self.metrics.to_device),
+            shape=imgs[0].shape,
             info=info, finalize=registry.request_finalize(op, canon),
             poisoned=self.faults.should_fire("poison"),
         )
@@ -297,7 +298,7 @@ class Service:
                         f"(pinned: {sorted(self._assets)})") from None
                 self.metrics.count("asset_hits")
             resolved.append(im)
-        imgs = tuple(np.asarray(im) for im in resolved)
+        imgs = tuple(self.metrics.to_host(im) for im in resolved)
         for im in imgs:
             if im.ndim != 2:
                 raise InvalidRequestError(
@@ -693,22 +694,17 @@ class Service:
 
     def _stage(self, info, key: BucketKey, requests, n_slots: int,
                batch: int = -1) -> tuple:
-        """Host staging: pad each canonical input to the bucket shape and
-        stack; sentinel slots keep the absorbing fill (they converge in
-        one chunk under the active-tile scheduler).  The ``serve.stage``
+        """Device staging: pad each canonical input to the bucket shape
+        and stack it where prepare left it (:func:`stage_planes`);
+        sentinel slots keep the absorbing fill (they converge in one
+        chunk under the active-tile scheduler).  The ``serve.stage``
         span of batch ``batch``."""
-        h, w = key.hw
-        dtype = np.dtype(key.dtype)
-        stacked = []
         with span("serve.stage", batch=batch):
-            for j in range(info.n_inputs):
-                buf = np.full((n_slots, h, w),
-                              pad_fill(dtype, info.fills[j]), dtype)
-                for i, req in enumerate(requests):
-                    rh, rw = req.shape
-                    buf[i, :rh, :rw] = np.asarray(req.inputs[j])
-                stacked.append(jnp.asarray(buf))
-        return tuple(stacked)
+            return tuple(
+                stage_planes([self.metrics.to_device(r.inputs[j])
+                              for r in requests],
+                             n_slots, key.hw, key.dtype, info.fills[j])
+                for j in range(info.n_inputs))
 
     # -- warm-up + introspection ------------------------------------------
 
@@ -747,10 +743,8 @@ class Service:
         if entry.exe is None or not entry.exe.refillable:
             return
         session = entry.exe.slot_session(self.refill_quantum)
-        dtype = np.dtype(key.dtype)
-        sentinels = tuple(
-            jnp.full(key.hw, pad_fill(dtype, info.fills[j]), dtype)
-            for j in range(info.n_inputs))
+        sentinels = tuple(stage_planes([], 1, key.hw, key.dtype, fill)[0]
+                          for fill in info.fills)
         state = session.admit(session.init(), 0, *sentinels)
         state, _, _ = session.round(state)
         jax.block_until_ready(session.extract(state))
