@@ -26,10 +26,10 @@ Compiled executables are cached in a module-level LRU keyed on the
 canonical expression graph plus the binding ``(shape, dtype, backend,
 plan, max_chunks, specialize)`` — an :class:`~repro.api.expr.Expr` is a
 frozen hashable dataclass, so the graph *is* the key.  ``cache_stats()``
-exposes hit/miss counters (surfaced by ``benchmarks/run.py --only
-pipeline``); the legacy operator sugar in ``core/operators.py`` and
-``kernels/ops.py`` goes through this cache on every call, which is what
-makes the thin-wrapper rebuild free in steady state.
+exposes hit/miss counters; the legacy operator sugar in
+``core/operators.py`` and ``kernels/ops.py`` goes through this cache on
+every call, which is what makes the thin-wrapper rebuild free in steady
+state.
 """
 from __future__ import annotations
 
@@ -251,7 +251,7 @@ def _build(expr, shape3, was_2d, dtype, backend, plan, max_chunks,
 
 
 def cache_stats() -> dict:
-    """Compile-cache counters (the pipeline benchmark's hit-rate row).
+    """Compile-cache counters.
 
     ``hits`` splits into ``structural_hits`` — the very same source
     graph was compiled before — and ``shared_hits`` — a *different*

@@ -421,13 +421,12 @@ class Executable:
 
     def stats(self) -> dict:
         """Static accounting of the compiled program (pads, launches,
-        refills): what the fusion tests and the pipeline benchmarks
-        count.  ``pads``/``crops`` are the pad/crop round-trips of one
-        execution (including the re-band round-trips at specialized
-        group boundaries); the legacy per-stage path pays one of each
-        per elementary operator stage.  ``plans`` counts the plan
-        groups, ``rebands`` the group boundaries values re-band
-        across.  ``convergent``/``chunk_budget_rec``/
+        refills): what the fusion tests count.  ``pads``/``crops`` are
+        the pad/crop round-trips of one execution (including the
+        re-band round-trips at specialized group boundaries); the
+        legacy per-stage path pays one of each per elementary operator
+        stage.  ``plans`` counts the plan groups, ``rebands`` the group
+        boundaries values re-band across.  ``convergent``/``chunk_budget_rec``/
         ``chunk_budget_qdt`` describe the watchdog configuration the
         convergence-driven segments run under; the *runtime* verdict
         for a particular execution comes from :meth:`run_batch_stats`
@@ -572,13 +571,15 @@ class Executable:
                 d, r = OPS.qdt_raw(vals[seg.srcs[0]])
                 vals[seg.dsts[0]], vals[seg.dsts[1]] = d, r
             elif seg.kind == "gdt":
-                from repro.kernels.ops import gdt_fixpoint_xla
+                from repro.kernels.ops import gdt_fixpoint_xla, gdt_stage
 
+                # bucket pads arrive as −inf, as on the pallas path
+                d0, ip, sp = gdt_stage(vals[seg.srcs[0]],
+                                       vals[seg.srcs[1]], seg.param("nu"))
                 # Jacobi advances every shortest path by ≥1 edge per
                 # iteration; H·W bounds any simple path's length.
                 vals[seg.dsts[0]] = gdt_fixpoint_xla(
-                    vals[seg.srcs[0]], vals[seg.srcs[1]],
-                    seg.param("lamb"), seg.param("nu"),
+                    d0, ip, sp, seg.param("lamb"),
                     self.height * self.width + 2,
                 )
             elif seg.kind == "point":

@@ -4,8 +4,8 @@ the near-parameter-insensitive baseline (§4.5, Table 5 footnote).
 
 Hybrid algorithm: raster + anti-raster sweep, then FIFO-queue
 propagation.  Serves as an independent correctness oracle for
-``kernels.ops.reconstruct`` (it shares no code with the jnp/Pallas
-paths) and as the baseline timing for the operator benchmarks.
+``kernels.ops.reconstruct``: it shares no code with the jnp/Pallas
+paths.
 """
 from __future__ import annotations
 

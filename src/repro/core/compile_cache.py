@@ -1,7 +1,7 @@
 """Where this repository's scripts keep JAX's persistent compilation
 cache.
 
-Scripts (``chip_smoke.py``, ``benchmarks/run.py``, ``examples/``) call
+Scripts (``chip_smoke.py``, ``bench/run.py``, ``examples/``) call
 :func:`enable_compile_cache` once at start-up; importing the library
 never touches the cache.  A second run in the same checkout then loads
 its compiled programs instead of compiling them again.

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 # ---------------------------------------------------------------------------
 # dtype lattice identities

@@ -1,4 +1,4 @@
-"""Synthetic test images for the morphology benchmarks.
+"""Synthetic test images for the morphology tests and examples.
 
 The paper uses USC-SIPI Male/Airport/Airplane (offline here); these
 generators produce images with the same *morphological* statistics that

@@ -1114,16 +1114,18 @@ def _raster_gdt(dp, ip, sp, plan: ChainPlan, lamb: float, max_rounds: int):
     return _stacked(d3), rounds, jnp.logical_not(changed)
 
 
-def gdt_fixpoint_xla(img3: jnp.ndarray, seeds3: jnp.ndarray, lamb: float,
-                     nu: float, max_iters: int) -> jnp.ndarray:
-    """Pure-jnp Jacobi oracle on unpadded (..., H, W) stacks — the "xla"
-    backend body, bit-exact with ``repro.gdt.reference`` by the shared
-    fold-cost argument.  Axis-polymorphic over leading batch dims (2-D
+def gdt_fixpoint_xla(d: jnp.ndarray, img3: jnp.ndarray, seeds3: jnp.ndarray,
+                     lamb: float, max_iters: int) -> jnp.ndarray:
+    """Pure-jnp Jacobi oracle on the (..., H, W) planes of
+    :func:`gdt_stage` — the "xla" backend body, bit-exact with
+    ``repro.gdt.reference`` by the shared fold-cost argument.  Like the
+    kernels, every step clamps the pad cells (seed marker < 0) back to
+    +inf, so a bucket-padded stack answers its real region exactly as
+    the unpadded one.  Axis-polymorphic over leading batch dims (2-D
     executables keep 2-D arrays end-to-end)."""
     dtype = img3.dtype
     inf = jnp.asarray(jnp.inf, dtype)
-    sc = jnp.clip(seeds3.astype(dtype), 0.0, 1.0)
-    d = (nu * (1.0 - sc)).astype(dtype)
+    in_pad = seeds3 < 0
 
     def shift(x, dy, dx, fill):
         pad = ([(0, 0)] * (x.ndim - 2)
@@ -1154,7 +1156,7 @@ def gdt_fixpoint_xla(img3: jnp.ndarray, seeds3: jnp.ndarray, lamb: float,
         cand = d
         for (dy, dx), w in zip(offsets, weights):
             cand = jnp.minimum(cand, shift(d, dy, dx, inf) + w)
-        return cand, d, it + 1
+        return jnp.where(in_pad, inf, cand), d, it + 1
 
     d, _, _ = jax.lax.while_loop(
         cond, body, (d, jnp.full_like(d, -inf), jnp.asarray(0, jnp.int32)))

@@ -48,8 +48,8 @@ TPU/Pallas port, one stage per module:
 ``metrics``
     The paper reports FPS per operator chain; ``ServeMetrics`` reports
     per-bucket latency percentiles, batch occupancy, cache hit-rate and
-    FPS / MPx-per-s in the same JSON schema as ``benchmarks/run.py
-    --json``.
+    FPS / MPx-per-s, through ``Service.stats()`` (what ``bench/``
+    reads).
 
 The convergence-driven operators routed through ``kernels.ops`` all run
 on the shared active-tile requeue driver (``_drive_scheduler``; the

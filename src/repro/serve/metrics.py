@@ -9,12 +9,6 @@ percentiles are computed over a bounded window of the most recent
 ``LATENCY_WINDOW`` requests per bucket, so a long-running service keeps
 O(1) memory per bucket while ``requests`` counts the full history.
 
-``bench_rows()`` / ``as_bench_json()`` emit the same row contract as
-``benchmarks/run.py`` (``name,us_per_call,derived`` rows and the
-``--json`` name → us_per_call mapping), so serving throughput lands in
-the same machine-readable perf trajectory as the kernel benchmarks.
-Every emitted field is documented in ``docs/BENCHMARKS.md``.
-
 :func:`span` is the one way the serving layer opens a host span: a
 ``jax.profiler.TraceAnnotation`` on the profiler's clock, so that a
 trace of a running service holds the ``serve.*`` spans beside the
@@ -33,10 +27,9 @@ import numpy as np
 #: Most recent per-bucket request latencies retained for percentiles.
 LATENCY_WINDOW = 4096
 
-#: Lifecycle counters always present in ``summary()["counters"]`` (and
-#: as ``serve/counters/*`` rows), so the benchmarks JSON schema is
-#: stable whether or not faults occurred.  Semantics (full contract in
-#: ``docs/ROBUSTNESS.md``):
+#: Lifecycle counters always present in ``summary()["counters"]``, so
+#: the schema is stable whether or not faults occurred.  Semantics
+#: (full contract in ``docs/ROBUSTNESS.md``):
 #:   rejected    admission-time typed rejections (invalid/non-finite/
 #:               unsupported dtype/unknown op)
 #:   shed        requests load-shed because the bounded queue was full
@@ -341,47 +334,3 @@ class ServeMetrics:
         for name in sorted(self.counters):
             out.setdefault(name, int(self.counters[name]))
         return out
-
-    def counter_rows(self) -> list[dict]:
-        """Lifecycle counters in the benchmarks row contract.  These
-        rows carry *counts*, not times — ``us_per_call`` holds the raw
-        count so the ``--json`` name → value schema can track them
-        across PRs (documented in ``docs/BENCHMARKS.md``)."""
-        return [
-            {
-                "name": f"serve/counters/{name}",
-                "us_per_call": float(value),
-                "derived": f"count={value}",
-            }
-            for name, value in self.counter_summary().items()
-        ]
-
-    def bench_rows(self, cache_stats: dict | None = None) -> list[dict]:
-        """Rows in the ``benchmarks.common.emit`` contract."""
-        rows = []
-        for label, b in sorted(self._buckets.items()):
-            if not b.requests:
-                continue
-            pct = self._percentiles(b.latencies_s)
-            fps, mpx = self._rates(b.requests, b.pixels, b.span_s)
-            derived = (
-                f"p50={pct['p50_ms']:.1f}ms p99={pct['p99_ms']:.1f}ms "
-                f"occ={b.occupancy:.2f} fps={fps:.1f} mpx/s={mpx:.1f}"
-            )
-            if b.errors:
-                derived += f" errors={b.errors}"
-            if b.degraded:
-                derived += f" degraded={b.degraded}"
-            if cache_stats is not None:
-                derived += f" cache_hit={cache_stats['hit_rate']:.2f}"
-            rows.append({
-                "name": f"serve/{label}",
-                "us_per_call": pct["mean_ms"] * 1e3,
-                "derived": derived,
-            })
-        return rows
-
-    def as_bench_json(self, cache_stats: dict | None = None) -> dict:
-        """name → us_per_call, the ``benchmarks/run.py --json`` schema."""
-        return {r["name"]: r["us_per_call"]
-                for r in self.bench_rows(cache_stats)}

@@ -778,12 +778,6 @@ class Service:
                     found[head] = None
         return found
 
-    def bench_rows(self) -> list[dict]:
-        """Rows in the benchmarks ``name,us_per_call,derived`` contract
-        (per-bucket latency/throughput plus the lifecycle counters)."""
-        return (self.metrics.bench_rows(self.cache.stats())
-                + self.metrics.counter_rows())
-
     def pending(self) -> int:
         """Requests awaiting a result: queued plus resident in slot
         engines (in-flight executor batches are not counted — they are

@@ -460,6 +460,4 @@ def test_stats_surface_faults_and_counters(rng):
     assert s["faults"]["seed"] == 9
     assert s["faults"]["armed"] == ["poison"]
     assert s["counters"]["poisoned"] == 1
-    rows = {r["name"]: r["us_per_call"] for r in svc.bench_rows()}
-    assert rows["serve/counters/poisoned"] == 1.0
-    assert rows["serve/counters/shed"] == 0.0  # schema stable at zero
+    assert s["counters"]["shed"] == 0  # schema stable at zero

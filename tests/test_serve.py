@@ -7,6 +7,8 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import api
+from repro import gdt as G
 from repro.core import morphology as M
 from repro.core import operators as OPS
 from repro.kernels import ops as K
@@ -35,31 +37,44 @@ def _image(rng, shape, dtype):
     return rng.integers(0, 255, shape).astype(dtype)
 
 
+def _compiled(expr, *images):
+    """An op with no eager entry point, compiled at the images' own
+    (unpadded) shape on the xla backend."""
+    return api.compile(expr, images[0].shape, images[0].dtype, "xla")(*images)
+
+
+_REC = {"erode": M.erode_reconstruct, "dilate": M.dilate_reconstruct}
+_GEO = {"erode": M.geodesic_erode, "dilate": M.geodesic_dilate}
+
+#: Reference for every registered serve op: the op called directly on
+#: the unpadded images on the xla backend, with the request's images as
+#: positional arguments and its params as keywords.
+DIRECT = {
+    "erode": lambda f, s: K.erode(f, s, backend="xla"),
+    "dilate": lambda f, s: K.dilate(f, s, backend="xla"),
+    "opening": lambda f, s: K.opening(f, s, backend="xla"),
+    "closing": lambda f, s: K.closing(f, s, backend="xla"),
+    "reconstruct": lambda mk, m, op="dilate": _REC[op](mk, m),
+    "geodesic": lambda mk, m, n, op="erode": _GEO[op](mk, m, n),
+    "qdt": lambda f: OPS.qdt_raw(f),  # (d, r)
+    "hmax": lambda f, h: OPS.hmax(f, h),
+    "dome": lambda f, h: OPS.dome(f, h),
+    "hfill": lambda f: OPS.hfill(f),
+    "raobj": lambda f: OPS.raobj(f),
+    "open_rec": lambda f, s: OPS.opening_by_reconstruction(f, s),
+    "asf": lambda f, s: OPS.asf(f, s),
+    "qdt_l1": lambda f: OPS.qdt(f),
+    "gdt": lambda f, sd, lamb=1.0, nu=1e6: G.gdt(f, sd, lamb, nu),
+    "seg_scribble": lambda f, sc, lamb=1.0, nu=1e6: _compiled(
+        G.seg_scribble_expr(lamb, nu), f, sc),
+    "seg_hmin": lambda f, h, lamb=1.0, nu=1e6: _compiled(
+        G.seg_hmin_expr(h, lamb, nu), f),
+}
+
+
 def _direct(op, images, params):
-    """Reference: each operator called directly on the unpadded image."""
-    f = jnp.asarray(images[0])
-    if op == "hmax":
-        return OPS.hmax(f, params["h"])
-    if op == "dome":
-        return OPS.dome(f, params["h"])
-    if op == "hfill":
-        return OPS.hfill(f)
-    if op == "raobj":
-        return OPS.raobj(f)
-    if op == "open_rec":
-        return OPS.opening_by_reconstruction(f, params["s"])
-    if op == "erode":
-        return K.erode(f, params["s"], backend="xla")
-    if op == "dilate":
-        return K.dilate(f, params["s"], backend="xla")
-    if op == "asf":
-        return OPS.asf(f, params["s"])
-    if op == "qdt":
-        return OPS.qdt_raw(f)  # (d, r)
-    if op == "reconstruct":
-        m = jnp.asarray(images[1])
-        return M.dilate_reconstruct(f, m)
-    raise AssertionError(op)
+    """Reference: the operator called directly on the unpadded images."""
+    return DIRECT[op](*map(jnp.asarray, images), **params)
 
 
 # ---------------------------------------------------------------------------
@@ -372,24 +387,3 @@ def test_registry_param_validation(rng):
     # params canonicalize to a stable hashable key (int h coerces float)
     spec = registry.get("hmax")
     assert spec.canonical_params({"h": 40}) == (("h", 40.0),)
-
-
-# ---------------------------------------------------------------------------
-# metrics: benchmarks JSON schema
-# ---------------------------------------------------------------------------
-
-
-def test_metrics_bench_json_schema(rng):
-    svc = Service(backend="xla", max_batch=2, max_delay_ms=1e9,
-                  pad_quantum=32, clock=FakeClock())
-    for _ in range(2):
-        svc.submit("erode", _image(rng, (24, 24), np.uint8),
-                   params={"s": 2})
-    svc.flush()
-    payload = svc.metrics.as_bench_json(svc.cache.stats())
-    assert payload  # same schema as benchmarks/run.py --json: name -> us
-    for name, us in payload.items():
-        assert name.startswith("serve/") and isinstance(us, float)
-    rows = svc.bench_rows()
-    assert all({"name", "us_per_call", "derived"} <= set(r) for r in rows)
-    assert "occ=" in rows[0]["derived"] and "cache_hit=" in rows[0]["derived"]
