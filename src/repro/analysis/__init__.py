@@ -16,6 +16,8 @@ from repro.analysis.dtypes import (
     check_distance_plane,
     check_fill_value,
     check_qdt_accumulator,
+    check_work_dtype,
+    check_work_dtypes,
 )
 from repro.analysis.findings import (
     CHECKS,
@@ -38,7 +40,7 @@ __all__ = [
     "CHECKS", "ERROR", "WARN", "Finding", "Report", "VerificationError",
     "SUPPORTED_DTYPES",
     "check_bucketer_fills", "check_distance_plane", "check_fill_value",
-    "check_qdt_accumulator",
+    "check_qdt_accumulator", "check_work_dtype", "check_work_dtypes",
     "check_coverage", "check_program",
     "check_block_specs", "check_partition", "check_plan_index_maps",
     "check_mosaic_readiness", "check_plan",

@@ -1,6 +1,7 @@
-"""Dtype-safety checks (check class b): absorbing fills + QDT overflow.
+"""Dtype-safety checks (check class b): absorbing fills, the kernels'
+work dtype and QDT overflow.
 
-Two families of facts are proved per supported dtype (the paper's
+Three families of facts are proved per supported dtype (the paper's
 char→double crossover set, §4):
 
 * the serve bucketer's pad fill (``serve/bucketer.py:pad_fill``) must
@@ -8,6 +9,10 @@ char→double crossover set, §4):
   (``kernels/common.py:ident_for``) and round-trip through the image
   dtype exactly — a fill one ULP off the lattice top is no longer
   absorbing for erosion and corrupts borders silently;
+* every value of the image dtype round-trips exactly through the
+  dtype the kernels compute in (``core/chain.py:work_dtype``) — a work
+  dtype that rounds an image value changes min/max results silently;
+  for uint8…int16 in float32 the fact is proved by exhaustion;
 * the quasi-distance transform accumulates residuals
   ``f − ε₁(f)`` into ``kernels/common.py:qdt_acc_dtype``; the residual
   telescoping bound is the lattice range (one erosion can drop a pixel
@@ -18,8 +23,8 @@ char→double crossover set, §4):
   (int32 images in an int32 accumulator, float64 in float32).
 
 Every check takes the *claimed* value as an argument with the
-production default, so the mutation self-tests can seed a wrong fill or
-an undersized accumulator and assert detection.
+production default, so the mutation self-tests can seed a wrong fill, a
+lossy work dtype or an undersized accumulator and assert detection.
 """
 from __future__ import annotations
 
@@ -87,6 +92,64 @@ def check_bucketer_fills(dtypes=SUPPORTED_DTYPES) -> list:
                     "dtype", ERROR, f"pad_fill({dt}, {which!r})",
                     f"serve fill {serve!r} != kernel halo identity "
                     f"{kern!r} (ident_for)"))
+    return out
+
+
+def check_work_dtype(image_dtype, work=None) -> list:
+    """Does ``work`` (default: the kernels' ``work_dtype``) hold every
+    value of ``image_dtype`` exactly, so that it round-trips and keeps
+    its order?
+
+    Integer images of at most 16 bits are enumerated, value by value (a
+    proof by exhaustion); wider ones are bounded: an integer dtype must
+    contain the image's range, a floating one must hold every integer of
+    it (magnitude ≤ 2^(mantissa + 1)).  A floating image needs a
+    floating work dtype at least as precise and as wide."""
+    if work is None:
+        from repro.core.chain import work_dtype
+        work = work_dtype(image_dtype)
+    img, wk = np.dtype(image_dtype), np.dtype(work)
+    subject = f"work dtype ({img.name} image → {wk.name})"
+    if img == wk:
+        return []
+    if np.issubdtype(img, np.floating):
+        fi = np.finfo(img)
+        exact = (np.issubdtype(wk, np.floating)
+                 and np.finfo(wk).nmant >= fi.nmant
+                 and np.finfo(wk).maxexp >= fi.maxexp
+                 and np.finfo(wk).minexp <= fi.minexp)
+    elif img.itemsize <= 2:
+        info = np.iinfo(img)
+        every = np.arange(int(info.min), int(info.max) + 1).astype(img)
+        with np.errstate(all="ignore"):
+            held = every.astype(wk)
+        # the same value, not merely the same bits back: a wrap keeps
+        # the round trip but breaks the order min/max rely on
+        exact = (np.array_equal(held.astype(np.float64),
+                                every.astype(np.float64))
+                 and np.array_equal(held.astype(img), every))
+    elif np.issubdtype(wk, np.floating):
+        info = np.iinfo(img)
+        exact = max(-int(info.min), int(info.max)) <= 2 ** (
+            np.finfo(wk).nmant + 1)
+    else:
+        exact = (np.issubdtype(wk, np.integer)
+                 and np.iinfo(wk).min <= np.iinfo(img).min
+                 and np.iinfo(wk).max >= np.iinfo(img).max)
+    if exact:
+        return []
+    return [Finding(
+        "dtype", ERROR, subject,
+        f"some {img.name} values do not survive the round trip through "
+        f"{wk.name} — the kernels would compute min/max on rounded "
+        "values and lose bit-exactness")]
+
+
+def check_work_dtypes(dtypes=SUPPORTED_DTYPES) -> list:
+    """Audit ``core.chain.work_dtype`` for every supported image dtype."""
+    out = []
+    for dt in dtypes:
+        out += check_work_dtype(dt)
     return out
 
 

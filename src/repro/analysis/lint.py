@@ -6,7 +6,8 @@ hook deferred — this CLI *is* the verifier) and runs the full-level
 static checks: halo/pad-state proofs, plan constraints, numeric
 index-map enumeration, cache-key mutation sweeps, dtype audits and
 Mosaic-readiness diagnostics.  The serve bucketer's pad fills are
-audited once against the kernel lattice identities on top.
+audited once against the kernel lattice identities on top, and the
+kernels' work dtype against every supported image dtype.
 
 Because the expression optimizer is on by default, every compiled
 case is the *rewritten* program — a clean sweep asserts the rewritten
@@ -73,9 +74,10 @@ def run_lint(ops=None, dtypes=DTYPES, shapes=SHAPES, backends=BACKENDS,
     from repro.api.compile import compile as api_compile
 
     total = Report(subject="repro.analysis.lint")
-    # the bucketer fill audit is global (all supported dtypes), not
-    # restricted to the sweep matrix — it is cheap and shape-free
+    # the bucketer fill and work-dtype audits are global (all supported
+    # dtypes), not restricted to the sweep matrix — cheap and shape-free
     total.extend(dtype_checks.check_bucketer_fills())
+    total.extend(dtype_checks.check_work_dtypes())
     n_cases = 0
     seen_exprs: dict = {}
     for label, expr, shape3, dtype, backend in iter_registry_cases(

@@ -89,12 +89,20 @@ SCHEDULES = ("wavefront", "raster")
 
 def work_dtype(dtype):
     """The dtype the kernels compute in: 8- and 16-bit integers widen to
-    int32 (Mosaic has no narrow-integer min/max, compare or select on
-    v5e); every other dtype is kept.  Widening is exact for min/max,
-    the mask clamps and the change test, so results stay bit-exact."""
+    float32; int32 and floating dtypes are kept.
+
+    Mosaic has no narrow-integer min/max, compare or select on v5e, so
+    narrow images must widen.  They widen to float32, not int32: v5e's
+    VPU has no int32 ``vmax``/``vmin`` (an int32 max or min lowers to a
+    ``vcmp`` and a ``vsel``), while a float32 max or min is one
+    ``vmax.f32``/``vmin.f32``.  Every 8- and 16-bit integer has
+    magnitude at most 65,535 < 2^24, so float32 holds it exactly, and
+    min/max, the mask clamps, the change test and the identity pins give
+    the values int32 would: results stay bit-exact.  int32 stays int32,
+    since float32 cannot hold every int32."""
     dtype = jnp.dtype(dtype)
     if jnp.issubdtype(dtype, jnp.integer) and dtype.itemsize < 4:
-        return jnp.dtype(jnp.int32)
+        return jnp.dtype(jnp.float32)
     return dtype
 
 

@@ -18,8 +18,21 @@ from repro.core.chain import side_width, work_dtype
 
 
 def widen(x):
-    """``x`` in its :func:`work_dtype` (a no-op for 32-bit dtypes)."""
-    return x.astype(work_dtype(x.dtype))
+    """``x`` in its :func:`work_dtype` (a no-op for 32-bit dtypes).
+    Narrow integers pass through int32: Mosaic refuses a direct
+    ``uint8 -> float32`` cast."""
+    wide = work_dtype(x.dtype)
+    if wide == x.dtype:
+        return x
+    return x.astype(jnp.int32).astype(wide)
+
+
+def narrow(x, dtype):
+    """``x``, in the work dtype of ``dtype``, back in ``dtype``: the
+    inverse of :func:`widen`, through int32 for the same reason."""
+    if x.dtype == dtype:
+        return x
+    return x.astype(jnp.int32).astype(dtype)
 
 
 def smem_spec():

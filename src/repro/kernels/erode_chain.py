@@ -40,7 +40,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (elementary_3x3, fused_steps, ident_for,
-                                  image_edges, kernel_name, row_specs, widen)
+                                  image_edges, kernel_name, narrow, row_specs,
+                                  widen)
 
 #: Name of the kernel (``pallas_call`` ``name=`` and ``kernel_metadata``).
 ROW_KERNEL = "erode_row"
@@ -57,7 +58,7 @@ def _chain_kernel(x_top, x_mid, x_bot, out, *, op: str, fuse_k: int,
 
     stack = fused_steps(lambda x: elementary_3x3(x, op), stack, fuse_k)
 
-    out[...] = stack[fuse_k : fuse_k + band_h, :].astype(out.dtype)
+    out[...] = narrow(stack[fuse_k : fuse_k + band_h, :], out.dtype)
 
 
 def chain_step(

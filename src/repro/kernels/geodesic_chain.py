@@ -54,7 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (assemble_tile, changed_flag, elementary_3x3,
                                   fused_steps, ident_for, image_edges,
-                                  kernel_name, row_specs, smem_spec,
+                                  kernel_name, narrow, row_specs, smem_spec,
                                   tile_edges, tile_specs, widen)
 
 #: Names of the row-band, tile and compact kernels (``pallas_call``
@@ -104,7 +104,7 @@ def _geodesic_kernel(
         stack = _geodesic_steps(stack, mask, op, fuse_k)
 
         centre = stack[fuse_k : fuse_k + band_h, :]
-        out[...] = centre.astype(out.dtype)
+        out[...] = narrow(centre, out.dtype)
         changed[i] = changed_flag(centre, f0)
 
 
@@ -193,7 +193,7 @@ def _geodesic_tile_kernel(
         stack = _geodesic_steps(stack, mask, op, fuse_k)
 
         centre = stack[fuse_k : fuse_k + band_h, fuse_k : fuse_k + tile_w]
-        out[...] = centre.astype(out.dtype)
+        out[...] = narrow(centre, out.dtype)
         changed[cell] = changed_flag(centre, widen(f_mid[...]))
 
 
@@ -272,7 +272,7 @@ def _geodesic_compact_kernel(
         centre0 = stack[lo:hi, cl:cr]
         stack = _geodesic_steps(stack, widen(m_patch[...]), op, fuse_k)
         centre = stack[lo:hi, cl:cr]
-        out[...] = centre.astype(out.dtype)
+        out[...] = narrow(centre, out.dtype)
         changed[c] = changed_flag(centre, centre0)
 
 

@@ -31,7 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (assemble_tile, changed_flag, elementary_3x3,
                                   ident_for, image_edges, kernel_name,
-                                  qdt_acc_dtype, row_specs, smem_spec,
+                                  narrow, qdt_acc_dtype, row_specs, smem_spec,
                                   tile_edges, tile_specs, widen)
 
 #: Names of the row-band, tile and compact kernels (``pallas_call``
@@ -98,7 +98,7 @@ def _qdt_kernel(
             ((fuse_k, fuse_k + band_h), (0, w)),
             fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre.astype(f_out.dtype)
+        f_out[...] = narrow(centre, f_out.dtype)
         r_out[...] = r
         d_out[...] = d
         changed[i] = changed_flag(centre, f0)
@@ -199,7 +199,7 @@ def _qdt_tile_kernel(
             ((fuse_k, fuse_k + band_h), (fuse_k, fuse_k + tile_w)),
             fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre.astype(f_out.dtype)
+        f_out[...] = narrow(centre, f_out.dtype)
         r_out[...] = r
         d_out[...] = d
         changed[cell] = changed_flag(centre, widen(f_mid[...]))
@@ -292,7 +292,7 @@ def _qdt_compact_kernel(
             stack, r_in[...], d_in[...], base[c],
             ((lo, hi), (cl, cr)), fuse_k=fuse_k, acc_dtype=acc_dtype,
         )
-        f_out[...] = centre.astype(f_out.dtype)
+        f_out[...] = narrow(centre, f_out.dtype)
         r_out[...] = r
         d_out[...] = d
         changed[c] = changed_flag(centre, centre0)

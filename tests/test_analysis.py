@@ -130,6 +130,21 @@ class TestDtypes:
     def test_unrepresentable_fill_detected(self):
         assert errors_of(A.check_fill_value("uint8", "hi", 255.5))
 
+    def test_work_dtype_round_trips(self):
+        # production rule: uint8…int16 proved exact in float32 by
+        # exhaustion, the 32- and 64-bit dtypes kept as they are
+        assert A.check_work_dtypes() == []
+        assert A.check_work_dtype("int8") == []
+
+    @pytest.mark.parametrize("img,work", [
+        ("int32", "float32"),    # 2^24 + 1 rounds
+        ("uint16", "float16"),   # 2049 rounds
+        ("int16", "uint16"),     # negatives wrap
+        ("float64", "float32"),  # precision and range lost
+    ])
+    def test_lossy_work_dtype_detected(self, img, work):
+        assert errors_of(A.check_work_dtype(img, work))
+
     def test_qdt_accumulator_overflow(self):
         # provable: uint16 residuals overflow an int16 accumulator
         assert errors_of(A.check_qdt_accumulator("uint16", "int16"))

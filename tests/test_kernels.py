@@ -7,8 +7,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.chain import (DEFAULT_VMEM_BUDGET, SCOPED_VMEM_BYTES,
-                              plan_chain, working_set_bytes)
-from repro.kernels import ops, ref
+                              plan_chain, work_dtype, working_set_bytes)
+from repro.kernels import erode_chain, geodesic_chain, ops, ref
 
 DTYPES = [np.uint8, np.uint16, np.float32, np.float64]
 SHAPES = [(64, 64), (100, 130), (33, 257), (128, 96)]
@@ -71,6 +71,106 @@ def test_qdt_kernel(rng, dtype):
     dw, rw = ref.qdt_raw(f)
     np.testing.assert_array_equal(np.asarray(d), np.asarray(dw))
     np.testing.assert_array_equal(np.asarray(r), np.asarray(rw))
+
+
+def _extreme_image(rng, shape, dtype):
+    """Values over the dtype's whole range, with its minimum and maximum
+    (the two identity pins) on whole rows, columns and single pixels."""
+    info = np.iinfo(dtype)
+    x = rng.integers(int(info.min), int(info.max) + 1, shape,
+                     endpoint=False).astype(dtype)
+    h, w = shape
+    x[rng.integers(0, h, 3)] = info.max
+    x[:, rng.integers(0, w, 3)] = info.min
+    x.flat[rng.integers(0, x.size, 16)] = info.max
+    x.flat[rng.integers(0, x.size, 16)] = info.min
+    x[0, 0], x[-1, -1] = info.max, info.min
+    return x
+
+
+def _geodesic_pair(rng, shape, dtype, op):
+    f = _extreme_image(rng, shape, dtype)
+    m = _extreme_image(rng, shape, dtype)
+    marker = np.maximum(f, m) if op == "erode" else np.minimum(f, m)
+    return jnp.asarray(marker), jnp.asarray(m)
+
+
+K, BAND, TILE = 8, 16, 128      # fuse_k, band_h, tile_w
+VMEM = SCOPED_VMEM_BYTES
+
+
+def _per_image(oracle, *planes):
+    """``oracle`` on each of two stacked images of two bands each (the
+    kernels pin halos at the stack's and at the images' edges)."""
+    halves = (slice(0, 2 * BAND), slice(2 * BAND, None))
+    return jnp.concatenate([oracle(*(p[s] for p in planes))
+                            for s in halves])
+
+
+def _row_geodesic(rng, dtype, op):
+    f, m = _geodesic_pair(rng, (4 * BAND, 72), dtype, op)
+    out, _ = geodesic_chain.geodesic_chain_step(
+        f, m, op=op, fuse_k=K, band_h=BAND, interpret=True,
+        vmem_limit_bytes=VMEM, bands_per_image=2)
+    return out, _per_image(lambda a, b: ref.geodesic_chain(a, b, K, op),
+                           f, m)
+
+
+def _tile_geodesic(rng, dtype, op):
+    f, m = _geodesic_pair(rng, (4 * BAND, 2 * TILE), dtype, op)
+    out, _ = geodesic_chain.geodesic_tile_step(
+        f, m, op=op, fuse_k=K, band_h=BAND, tile_w=TILE, interpret=True,
+        vmem_limit_bytes=VMEM, bands_per_image=2)
+    return out, _per_image(lambda a, b: ref.geodesic_chain(a, b, K, op),
+                           f, m)
+
+
+def _compact_geodesic(rng, dtype, op):
+    # three patches, each a centre and its K-wide halos; the centre of
+    # K steps over the whole patch is exact
+    ph, pw = BAND + 2 * K, TILE + 2 * K
+    f, m = _geodesic_pair(rng, (3 * ph, pw), dtype, op)
+    out, _ = geodesic_chain.geodesic_compact_step(
+        f, m, jnp.ones((3, 1), jnp.int32), op=op, fuse_k=K, band_h=BAND,
+        tile_w=TILE, interpret=True, vmem_limit_bytes=VMEM)
+    want = jnp.concatenate([
+        ref.geodesic_chain(f[c * ph:(c + 1) * ph], m[c * ph:(c + 1) * ph],
+                           K, op)[K:K + BAND, K:K + TILE]
+        for c in range(3)])
+    return out, want
+
+
+def _row_chain(rng, dtype, op):
+    x = jnp.asarray(_extreme_image(rng, (4 * BAND, 72), dtype))
+    out = erode_chain.chain_step(x, op=op, fuse_k=K, band_h=BAND,
+                                 interpret=True, vmem_limit_bytes=VMEM,
+                                 bands_per_image=2)
+    return out, _per_image(lambda a: ref.chain(a, K, op), x)
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int16])
+@pytest.mark.parametrize("op", ["erode", "dilate"])
+@pytest.mark.parametrize("kernel", [_row_geodesic, _tile_geodesic,
+                                    _compact_geodesic, _row_chain],
+                         ids=["geodesic_row", "geodesic_tile",
+                              "geodesic_compact", "erode_row"])
+def test_kernel_exact_at_dtype_extremes(rng, kernel, op, dtype):
+    """The kernels compute 8- and 16-bit images in float32
+    (``work_dtype``): bit-exact against the jnp reference on images
+    that hold the dtype's minimum and maximum beside the pinned halos."""
+    out, want = kernel(rng, dtype, op)
+    assert out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("dtype,wide", [
+    (np.uint8, np.float32), (np.int8, np.float32),
+    (np.uint16, np.float32), (np.int16, np.float32),
+    (np.int32, np.int32), (np.float32, np.float32),
+    (np.float64, np.float64),
+])
+def test_work_dtype(dtype, wide):
+    assert work_dtype(dtype) == np.dtype(wide)
 
 
 def test_plan_chain_invariants():
